@@ -536,7 +536,7 @@ def fill_comparison():
     prob = dense_random_instance()
     g = gamma_matrix(prob)
     k, r = prob.num_servers, prob.num_resources
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         args = tuple(jnp.asarray(a, jnp.float64)
                      for a in (prob.demands, prob.capacities, prob.weights,
                                g))
@@ -611,7 +611,7 @@ def sparse_scale():
     prob, _ = sparse_cell_instance()        # the pinned 20k x 256 @ ~3%
     g = gamma_matrix(prob)
     lay = BucketedLayout.from_support(g > 0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         args = tuple(jnp.asarray(a, jnp.float64)
                      for a in (prob.demands, prob.capacities,
                                prob.weights, g))
@@ -714,7 +714,7 @@ def convergence_comparison():
               f"rejects={int(out_a[4])}{note}")
         return res
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pair("dense", dense_random_instance(), 1e-5, 256, fill="bisect")
         cell, _, _ = cell_cluster_instance(num_users=256, num_servers=32,
                                            cells=4, seed=0)

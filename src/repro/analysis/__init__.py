@@ -11,8 +11,8 @@ from rotting as the engine grows new axes and backends:
   numpy-on-jnp, no host I/O (codes ``JP2xx``).
 * ``kernel-triples`` -- every ``kernels/*/`` package ships the
   ``kernel.py``/``ops.py``/``ref.py`` triple with matching public
-  signatures, uses the ``_compat.CompilerParams`` shim, and is exercised by
-  a test file (codes ``KT3xx``).
+  signatures, never names the removed ``TPUCompilerParams``, and is
+  exercised by a test file (codes ``KT3xx``).
 * ``observability`` -- every ``SolveInfo``/``ChurnRecord`` field is
   populated by each declared backend or explicitly waived (codes ``OB4xx``).
 * ``docstrings`` -- public-symbol docstring coverage stays above the floor

@@ -7,10 +7,10 @@ stripped, then equality / containment / a >=4-char common prefix; a
 single-public-function module pairs by elimination) and must agree on
 positional arity and positional parameter names — keyword-only tuning
 knobs (``block_q``, ``interpret``, ...) are ops-side freedom. Pallas
-compiler params must come from the ``_compat.CompilerParams`` shim, never
-the raw jax name (the ``TPUCompilerParams`` -> ``CompilerParams`` rename
-is exactly the breakage the shim absorbs). Each package must be imported
-by its declared test file so the CI interpret lane actually runs it.
+compiler params are ``pltpu.CompilerParams``; the pre-rename
+``TPUCompilerParams`` no longer exists in the supported jax line and is
+flagged wherever it appears. Each package must be imported by its
+declared test file so the CI interpret lane actually runs it.
 
 Finding codes::
 
@@ -18,7 +18,7 @@ Finding codes::
     KT302  public ops function with no reference twin
     KT303  ops/ref positional arity mismatch
     KT304  ops/ref positional parameter names drift
-    KT305  raw (non-shim) CompilerParams/TPUCompilerParams usage
+    KT305  removed ``TPUCompilerParams`` name used
     KT306  package not imported by its declared test file
 """
 from __future__ import annotations
@@ -123,30 +123,25 @@ def run(model: RepoModel, config: Dict) -> List[Finding]:
                     f"package ships the kernel/ops/ref triple"))
             triple[fname] = mod
 
-        # -- shim discipline on all present triple files -------------------
+        # -- compiler params: the removed pre-rename name -------------------
         for fname, mod in triple.items():
             if mod is None:
                 continue
             for node in ast.walk(mod.tree):
                 bad: Optional[Tuple[int, str]] = None
-                if isinstance(node, ast.ImportFrom) and node.module \
-                        and "pallas" in node.module:
+                if isinstance(node, ast.ImportFrom) and node.module:
                     for a in node.names:
-                        if a.name in ("CompilerParams", "TPUCompilerParams"):
+                        if a.name == "TPUCompilerParams":
                             bad = (node.lineno, f"from {node.module} "
                                                 f"import {a.name}")
                 elif isinstance(node, ast.Attribute) \
-                        and node.attr in ("CompilerParams",
-                                          "TPUCompilerParams"):
-                    dn = dotted_name(node) or node.attr
-                    if not dn.startswith("_compat."):
-                        bad = (node.lineno, dn)
+                        and node.attr == "TPUCompilerParams":
+                    bad = (node.lineno, dotted_name(node) or node.attr)
                 if bad is not None:
                     findings.append(_finding(
                         "KT305", mod.rel, bad[0], f"{pkg}/{fname}",
-                        f"raw compiler-params name ({bad[1]}) — use the "
-                        f"_compat.CompilerParams shim (absorbs the "
-                        f"TPUCompilerParams rename)"))
+                        f"{bad[1]} was removed from jax — use "
+                        f"pltpu.CompilerParams"))
 
         # -- ops/ref signature conformance ----------------------------------
         ops_mod, ref_mod = triple.get("ops.py"), triple.get("ref.py")

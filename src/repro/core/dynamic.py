@@ -119,21 +119,32 @@ def _tick_jax_bucketed_fn():
 
 
 def min_vds_guarded(x: np.ndarray, weights: np.ndarray, gamma: np.ndarray,
-                    active: np.ndarray, *, interpret: bool = True):
+                    active: np.ndarray):
     """The Eq. 16 reduction with the inactive/zero-weight mask applied
     BEFORE the division: a zero-weight user (weights are validated > 0 at
     construction, but callers can rescale the array in place) must be
     excluded exactly like an inactive one, not turn a server's min into
     inf/NaN. Shared by ``DistributedPSDSF.min_vds`` and the churn
-    simulator's telemetry (imported from here as public API)."""
+    simulator's telemetry (imported from here as public API).
+
+    The backend decides how the Pallas kernel runs: compiled on ``tpu``,
+    the Pallas interpreter on ``cpu`` (tests); any other platform raises
+    rather than silently interpreting on a device."""
+    import jax
+
     from repro.kernels.psdsf_vds.ops import min_vds_padded
 
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the psdsf_vds kernel runs compiled on tpu or interpreted on "
+            f"cpu; backend {backend!r} has neither")
     mask = np.asarray(active, dtype=bool) & (weights > 0)
     with np.errstate(divide="ignore", invalid="ignore"):
         x_over_phi = np.where(mask, x.sum(axis=1)
                               / np.where(mask, weights, 1.0), 0.0)
     return min_vds_padded(x_over_phi, np.where(mask[:, None], gamma, 0.0),
-                          interpret=interpret)
+                          interpret=backend == "cpu")
 
 
 class DistributedPSDSF:
@@ -247,7 +258,7 @@ class DistributedPSDSF:
         import contextlib
 
         import jax
-        return (jax.experimental.enable_x64() if self._x64
+        return (jax.enable_x64(True) if self._x64
                 else contextlib.nullcontext())
 
     # -- churn -------------------------------------------------------------
@@ -429,10 +440,10 @@ class DistributedPSDSF:
         return Allocation(self.problem, x)
 
     # -- telemetry ----------------------------------------------------------
-    def min_vds(self, interpret: bool = True):
+    def min_vds(self):
         """Per-server (min normalized VDS, argmin user) over active users —
-        Eq. 16 via the Pallas ``psdsf_vds`` reduction. ``interpret=True``
-        runs the kernel in interpreter mode (CPU CI); pass False on TPU.
+        Eq. 16 via the Pallas ``psdsf_vds`` reduction, compiled on TPU and
+        interpreted on CPU (see ``min_vds_guarded``).
 
         Servers where no active user is eligible report BIG (~3e38); that
         includes the all-inactive edge case. Users whose weight has been
@@ -441,7 +452,7 @@ class DistributedPSDSF:
         poison the server min with inf/NaN.
         """
         return min_vds_guarded(self.x, self.problem.weights, self.gamma,
-                                self.active, interpret=interpret)
+                                self.active)
 
     def allocation(self) -> Allocation:
         """Snapshot of the current state as an :class:`Allocation`."""

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -96,7 +95,7 @@ def decode_attention_grouped(q, k, v, kv_len, *, block_k: int = 512,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(kv_len, jnp.int32).reshape(1), q, k, v)

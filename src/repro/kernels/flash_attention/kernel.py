@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -108,7 +107,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 BIG = 3.0e38
 TOL = 1e-9
@@ -176,7 +175,7 @@ def fill_event_levels(floors, rate, demands, caps, frozen, saturated, level,
             pltpu.VMEM((block_k, r), dt),
             pltpu.VMEM((block_k, r), dt),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(floors, rate, demands, caps, frozen, saturated, level[None, :])

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 BIG = 3.0e38
 TOL = 1e-9
@@ -187,7 +186,7 @@ def fill_event_levels_bucketed(floors, rate, dem_b, caps, frozen, saturated,
             pltpu.VMEM((block_k, r), dt),
             pltpu.VMEM((block_k, r), dt),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(floors, rate, dem_b, caps, frozen, saturated, level[None, :])

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 BIG = 3.0e38
 
@@ -83,7 +82,7 @@ def vds_argmin(x_over_phi, gamma, *, block_n: int = 256, block_k: int = 128,
             pltpu.VMEM((1, block_k), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.int32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x_over_phi.astype(jnp.float32)[:, None], gamma)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref,
                 state_scr, *, chunk: int):
@@ -94,7 +92,7 @@ def ssd_scan(x, dt, a, b_mat, c_mat, *, chunk: int = 128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(a, jnp.float32), x, dt4, b_mat, c_mat)

@@ -129,7 +129,7 @@ class ChurnSimulator:
                  warm_start: bool = True, compare_cold: bool = False,
                  max_rounds: int = 256, tol: float = 1e-6,
                  initial_active: Optional[np.ndarray] = None,
-                 telemetry: bool = True, interpret_vds: bool = True,
+                 telemetry: bool = True,
                  mechanism: Optional[str] = None, placement: str = "level",
                  fill: str = "event", round: str = "gauss",
                  layout: str = "auto", accel: str = "none"):
@@ -174,7 +174,6 @@ class ChurnSimulator:
         self.max_rounds = max_rounds
         self.tol = tol
         self.telemetry = telemetry
-        self.interpret_vds = interpret_vds
         n, k = problem.num_users, problem.num_servers
         self.active = (np.ones(n, dtype=bool) if initial_active is None
                        else np.asarray(initial_active, dtype=bool).copy())
@@ -371,7 +370,7 @@ class ChurnSimulator:
 
         g = gamma_matrix(self._effective_problem())
         mn, _ = min_vds_guarded(self.x, self.problem.weights, g,
-                                 self.active, interpret=self.interpret_vds)
+                                 self.active)
         i = int(np.argmin(mn))
         return float(mn[i]), i
 

@@ -238,10 +238,10 @@ class TestKernelTriples:
     def test_missing_file_raw_params_and_no_test(self, tmp_path):
         model = _model(tmp_path, {
             "src/k/badpkg/kernel.py": """\
-                from jax.experimental.pallas import CompilerParams
+                from jax.experimental.pallas.tpu import TPUCompilerParams
 
                 def _kernel():
-                    return CompilerParams
+                    return TPUCompilerParams
             """,
             "src/k/badpkg/ops.py": """\
                 def op(a, b):
@@ -300,10 +300,10 @@ class TestKernelTriples:
     def test_conforming_package_clean(self, tmp_path):
         model = _model(tmp_path, {
             "src/k/goodpkg/kernel.py": """\
-                from repro.kernels import _compat
+                from jax.experimental.pallas import tpu as pltpu
 
                 def _kernel():
-                    return _compat.CompilerParams(dimension_semantics=())
+                    return pltpu.CompilerParams(dimension_semantics=())
             """,
             "src/k/goodpkg/ops.py": """\
                 def run_op(q, k, *, block_q=128, interpret=False):

@@ -143,7 +143,7 @@ class TestEngineParity:
         prob = google_cluster_instance()[0]
         sim = DistributedPSDSF(prob, engine="jax")
         sim.tick()
-        mn, arg = sim.min_vds(interpret=True)
+        mn, arg = sim.min_vds()
         g = np.where(sim.active[:, None], sim.gamma, 0.0)
         ref_mn, ref_arg = vds_argmin_ref(
             jnp.asarray(sim.x.sum(axis=1) / prob.weights, jnp.float32),

@@ -63,7 +63,7 @@ def _jax_solve(prob, mode="rdm", dtype=None, **kw):
 @pytest.fixture()
 def x64():
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

@@ -1,8 +1,9 @@
-"""Interpret-mode lane for the scheduler Pallas kernels (ISSUE-7 CI
-satellite): ``psdsf_vds``, ``psdsf_fill``, ``psdsf_fill_bucketed`` and
-the ``_compat`` shim, all runnable on a CPU-only box
-(``JAX_PLATFORMS=cpu``) — this file IS the CI "kernels (interpret)"
-step, so it must stay importable and green with no TPU anywhere.
+"""Interpret-mode lane for the scheduler Pallas kernels: ``psdsf_vds``,
+``psdsf_fill``, ``psdsf_fill_bucketed`` and their compiler params, all
+runnable on a CPU-only box (``JAX_PLATFORMS=cpu``) — this file IS the CI
+"kernels (interpret)" step, so it must stay importable and green with no
+TPU anywhere. The compiled lowering of the same kernels is checked by
+``tests/test_tpu_compile.py``.
 
 The deep fill-engine parity suite lives in ``tests/test_fill_bisect.py``;
 here each kernel is exercised against its independent oracle through the
@@ -24,21 +25,22 @@ from conftest import random_problems
 @pytest.fixture()
 def x64():
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 
-class TestCompatShim:
+class TestCompilerParams:
     def test_compiler_params_resolves(self):
-        from repro.kernels import _compat
-        params = _compat.CompilerParams(
+        from jax.experimental.pallas import tpu as pltpu
+        assert not hasattr(pltpu, "TPUCompilerParams")
+        params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
         assert params.dimension_semantics == ("parallel", "arbitrary")
 
-    def test_all_kernels_import_the_shim(self):
-        # every kernel module must route its compiler params through the
-        # shim — a direct pltpu.TPUCompilerParams reference would break on
-        # one side of the jax rename this file exists to absorb
+    def test_all_kernels_use_pltpu_compiler_params(self):
+        # every scheduler kernel passes pltpu.CompilerParams directly and
+        # never names the pre-rename TPUCompilerParams, which the supported
+        # jax line no longer ships
         import ast
         import inspect
 
@@ -50,6 +52,9 @@ class TestCompatShim:
             names = {n.attr for n in ast.walk(tree)
                      if isinstance(n, ast.Attribute)}
             assert "TPUCompilerParams" not in names, mod.__name__
+            assert "CompilerParams" in names, mod.__name__
+            assert "_compat" not in {n.id for n in ast.walk(tree)
+                                     if isinstance(n, ast.Name)}
 
 
 class TestPsdsfVds:

@@ -32,7 +32,7 @@ PARITY_ATOL = 1e-9
 @pytest.fixture()
 def x64():
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

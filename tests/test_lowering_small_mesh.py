@@ -27,8 +27,11 @@ SCRIPT = textwrap.dedent("""
     from repro.train.step import build_decode_step
     from repro.models import abstract_params
 
+    # Auto axes: jax 0.9 makes mesh axes Explicit by default, and the
+    # model code places activations with with_sharding_constraint
     mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         devices=jax.devices()[:8])
+                         devices=jax.devices()[:8],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     results = {}
     for arch in ("qwen3_1_7b", "jamba_v0_1_52b", "granite_moe_3b_a800m"):
         cfg = get_smoke_config(arch)
