@@ -149,29 +149,6 @@ def phase_parity(num_users: int = PARITY_USERS,
                 cold_gap=gap, cold_tight=not got_info.approx)
 
 
-class _CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, read from
-    ``jax.monitoring`` while registered."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def _listen(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def __enter__(self):
-        jax.monitoring.register_event_duration_secs_listener(self._listen)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_duration_listener(self._listen)
-
-
 def _stream(num_users: int, num_servers: int, steps: int, seed: int):
     events = poisson_churn_events(num_users, num_servers, horizon=steps,
                                   arrival_rate=4.0, departure_rate=4.0,
@@ -188,7 +165,9 @@ def phase_churn(problem=None, steps: int = 10, seed: int = 0,
     telemetry on) through a seeded Poisson stream. Step 0 is the cold
     solve to the initial fixed point and carries the compiles; every step
     must sit inside the loose band of ``engine.ensure_converged`` with a
-    feasible, finite allocation and a finite ``min_vds``."""
+    feasible, finite allocation and a finite ``min_vds``. Each step's
+    ``compile_s`` is the ``compile_ms`` counter of its ``churn.step`` span
+    (``repro.core.trace``)."""
     prob = sparse_cell_instance()[0] if problem is None else problem
     sim = ChurnSimulator(prob, telemetry=True)
     _check(sim.layout == "bucketed",
@@ -202,35 +181,33 @@ def phase_churn(problem=None, steps: int = 10, seed: int = 0,
 
     tight = 0
     records = []
-    with _CompileClock() as clock:
-        for t, evs in [(0.0, [])] + sorted(batches.items()):
-            before = clock.seconds
-            rec = sim.step(evs, t)
-            compile_s = clock.seconds - before
-            alloc = sim.allocation()
-            g_act = np.where(sim.active[:, None],
-                             gamma_matrix(alloc.problem), 0.0)
-            info = SolveInfo.from_residual(rec.rounds, rec.residual,
-                                           float(g_act.max(initial=1.0)),
-                                           sim.tol)
-            kinds = ",".join(sorted({e.kind for e in evs})) or "none"
-            log(f"phase2 step={int(t)} events={rec.n_events} kinds={kinds} "
-                f"active={rec.active_users} layout={rec.layout} "
-                f"bucket_max={rec.bucket_max} rounds={rec.rounds} "
-                f"residual={rec.residual!r} "
-                f"tight={rec.rounds_to_tol > 0} "
-                f"solve_ms={rec.solve_ms!r} compile_s={compile_s!r} "
-                f"min_vds={rec.min_vds!r}")
-            engine.ensure_converged(info, f"phase2 step {int(t)}")
-            ok, msg = check_feasible_rdm(alloc, tol=FEAS_TOL)
-            _check(ok, f"phase2 step {int(t)}: {msg}")
-            _check(np.isfinite(alloc.x).all() and np.isfinite(rec.min_vds),
-                   f"phase2 step {int(t)}: non-finite state or telemetry")
-            _check(not alloc.x[~sim.active].any(),
-                   f"phase2 step {int(t)}: a departed user holds tasks")
-            tight += rec.rounds_to_tol > 0
-            records.append(dict(rounds=rec.rounds, solve_ms=rec.solve_ms,
-                                compile_s=compile_s))
+    for t, evs in [(0.0, [])] + sorted(batches.items()):
+        rec = sim.step(evs, t)
+        compile_s = rec.trace.counters.get("compile_ms", 0.0) / 1e3
+        alloc = sim.allocation()
+        g_act = np.where(sim.active[:, None],
+                         gamma_matrix(alloc.problem), 0.0)
+        info = SolveInfo.from_residual(rec.rounds, rec.residual,
+                                       float(g_act.max(initial=1.0)),
+                                       sim.tol)
+        kinds = ",".join(sorted({e.kind for e in evs})) or "none"
+        log(f"phase2 step={int(t)} events={rec.n_events} kinds={kinds} "
+            f"active={rec.active_users} layout={rec.layout} "
+            f"bucket_max={rec.bucket_max} rounds={rec.rounds} "
+            f"residual={rec.residual!r} "
+            f"tight={rec.rounds_to_tol > 0} "
+            f"solve_ms={rec.solve_ms!r} compile_s={compile_s!r} "
+            f"min_vds={rec.min_vds!r}")
+        engine.ensure_converged(info, f"phase2 step {int(t)}")
+        ok, msg = check_feasible_rdm(alloc, tol=FEAS_TOL)
+        _check(ok, f"phase2 step {int(t)}: {msg}")
+        _check(np.isfinite(alloc.x).all() and np.isfinite(rec.min_vds),
+               f"phase2 step {int(t)}: non-finite state or telemetry")
+        _check(not alloc.x[~sim.active].any(),
+               f"phase2 step {int(t)}: a departed user holds tasks")
+        tight += rec.rounds_to_tol > 0
+        records.append(dict(rounds=rec.rounds, solve_ms=rec.solve_ms,
+                            compile_s=compile_s))
     event_steps = records[1:]
     log(f"phase2 summary steps={len(event_steps)} "
         f"tight_certified={tight}/{len(records)} "

@@ -32,6 +32,7 @@ import numpy as np
 from .gamma import gamma_matrix
 from .psdsf import (server_fill_rdm, server_fill_rdm_bisect, server_fill_tdm,
                     server_fill_tdm_bisect)
+from .trace import span
 from .types import Allocation, AllocationProblem
 
 _ENGINES = ("numpy", "jax")
@@ -139,12 +140,13 @@ def min_vds_guarded(x: np.ndarray, weights: np.ndarray, gamma: np.ndarray,
         raise RuntimeError(
             f"the psdsf_vds kernel runs compiled on tpu or interpreted on "
             f"cpu; backend {backend!r} has neither")
-    mask = np.asarray(active, dtype=bool) & (weights > 0)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        x_over_phi = np.where(mask, x.sum(axis=1)
-                              / np.where(mask, weights, 1.0), 0.0)
-    return min_vds_padded(x_over_phi, np.where(mask[:, None], gamma, 0.0),
-                          interpret=backend == "cpu")
+    with span("vds.prep"):
+        mask = np.asarray(active, dtype=bool) & (weights > 0)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            x_over_phi = np.where(mask, x.sum(axis=1)
+                                  / np.where(mask, weights, 1.0), 0.0)
+        gamma = np.where(mask[:, None], gamma, 0.0)
+    return min_vds_padded(x_over_phi, gamma, interpret=backend == "cpu")
 
 
 class DistributedPSDSF:

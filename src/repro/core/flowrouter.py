@@ -96,7 +96,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from .trace import Tracer
+from .trace import Tracer, count, span
 from .types import AllocationProblem
 
 #: relative tolerance deciding whether a candidate's slack proves it
@@ -399,6 +399,7 @@ class RouterState:
                 np.array([-1.0]), [None], b_eq)
         stats.lp_calls += 1
         stats.lp_iters += nit
+        count("lp_calls")
         return x[:self.p], float(x[self.p]), duals
 
     def _certificate_lp(self, cand, b_eq, level, stats):
@@ -422,6 +423,7 @@ class RouterState:
                 np.full(m, -1.0), np.full(m, capv), b_eq)
         stats.lp_calls += 1
         stats.lp_iters += nit
+        count("lp_calls")
         return x[:self.p], x[self.p:]
 
     # -- stage machinery -----------------------------------------------------
@@ -437,15 +439,14 @@ class RouterState:
             cand = cand[~raisable]
         return cand, x_arc
 
-    def _run_stages(self, st: _SolveState, stats: RouterStats,
-                    tracer: Tracer) -> None:
+    def _run_stages(self, st: _SolveState, stats: RouterStats) -> None:
         """Run the water-filling loop from ``st`` until everyone froze."""
         while st.active.any():
             stats.stages += 1
             if stats.stages > self.nu + 1:            # theory: <= |users|
                 raise RuntimeError(
                     "lexmm did not converge in |users| stages")
-            with tracer.span(f"stage{stats.stages}"):
+            with span(f"lexmm.stage{stats.stages}"):
                 act_idx = np.nonzero(st.active)[0]
                 b_eq = np.where(st.active, self.r * st.level, st.t_eq)
                 x_arc, delta, duals = self._increment_lp(
@@ -514,10 +515,10 @@ class RouterState:
             self.last_stats = stats
             return np.zeros(self.shape), stats
         act_mask = self._mask(active)
-        tracer = Tracer()
         st = _SolveState(t_eq=np.zeros(self.nu), active=act_mask.copy(),
                          level=0.0, x_arc=np.zeros(self.p))
-        self._run_stages(st, stats, tracer)
+        with Tracer("lexmm.solve") as tracer:
+            self._run_stages(st, stats)
         return self._store(st, act_mask, stats, tracer, t0), stats
 
     def resolve(self, active=None) -> Tuple[np.ndarray, RouterStats]:
@@ -549,38 +550,39 @@ class RouterState:
         stats = RouterStats(
             mode="verify" if (act_mask == self._act_mask).all()
             else "incremental")
-        tracer = Tracer()
         st = _SolveState(t_eq=np.zeros(self.nu),
                          active=np.zeros(self.nu, dtype=bool),
                          level=0.0, x_arc=np.zeros(self.p))
         frozen_before = np.zeros(self.nu, dtype=bool)
         verified = 0
-        for stage in self._trace:
-            keep = stage.frozen[act_mask[stage.frozen]]
-            if keep.size == 0:
-                break     # the whole batch departed: maximality unprovable
-            b_eq = np.where(
-                act_mask,
-                np.where(frozen_before, st.t_eq, self.r * stage.level), 0.0)
-            try:
-                with tracer.span(f"verify{verified + 1}"):
-                    x_c, eps = self._certificate_lp(keep, b_eq, stage.level,
-                                                    stats)
-            except RuntimeError:
-                break     # infeasible under the new rhs: re-solve from here
-            if (eps > _BLOCK_RTOL * max(stage.level, 1e-300)).any():
-                break     # someone traced-frozen is now raisable
-            st.x_arc = x_c
-            st.t_eq[keep] = self.r[keep] * stage.level
-            frozen_before[keep] = True
-            st.level = stage.level
-            st.trace.append(_Stage(stage.level, keep))
-            verified += 1
-        stats.warm_hits = verified
-        stats.stages = verified
-        st.active = act_mask & ~frozen_before
-        if st.active.any():
-            self._run_stages(st, stats, tracer)
+        with Tracer("lexmm.resolve") as tracer:
+            for stage in self._trace:
+                keep = stage.frozen[act_mask[stage.frozen]]
+                if keep.size == 0:
+                    break  # the whole batch departed: maximality unprovable
+                b_eq = np.where(
+                    act_mask,
+                    np.where(frozen_before, st.t_eq, self.r * stage.level),
+                    0.0)
+                try:
+                    with span(f"lexmm.verify{verified + 1}"):
+                        x_c, eps = self._certificate_lp(
+                            keep, b_eq, stage.level, stats)
+                except RuntimeError:
+                    break  # infeasible under the new rhs: re-solve from here
+                if (eps > _BLOCK_RTOL * max(stage.level, 1e-300)).any():
+                    break     # someone traced-frozen is now raisable
+                st.x_arc = x_c
+                st.t_eq[keep] = self.r[keep] * stage.level
+                frozen_before[keep] = True
+                st.level = stage.level
+                st.trace.append(_Stage(stage.level, keep))
+                verified += 1
+            stats.warm_hits = verified
+            stats.stages = verified
+            st.active = act_mask & ~frozen_before
+            if st.active.any():
+                self._run_stages(st, stats)
         return self._store(st, act_mask, stats, tracer, t0), stats
 
     def update(self, level_gamma: Optional[np.ndarray] = None,

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core.trace import count, span
+
 from .kernel import vds_argmin  # noqa: F401 (public op == kernel entry)
 
 
@@ -17,16 +19,21 @@ def min_vds_padded(x_over_phi, gamma, *, interpret: bool = False):
     """
     import jax.numpy as jnp
 
-    x_over_phi = np.asarray(x_over_phi)
-    gamma = np.asarray(gamma)
-    n, k = gamma.shape
-    block_n, block_k = min(256, max(n, 1)), min(128, max(k, 1))
-    n_pad, k_pad = -n % block_n, -k % block_k
-    if n_pad or k_pad:
-        x_over_phi = np.pad(x_over_phi, (0, n_pad))
-        gamma = np.pad(gamma, ((0, n_pad), (0, k_pad)))
-    mn, arg = vds_argmin(jnp.asarray(x_over_phi, jnp.float32),
-                         jnp.asarray(gamma, jnp.float32),
-                         block_n=block_n, block_k=block_k,
-                         interpret=interpret)
-    return np.asarray(mn)[:k], np.asarray(arg)[:k]
+    with span("vds.prep"):
+        x_over_phi = np.asarray(x_over_phi)
+        gamma = np.asarray(gamma)
+        n, k = gamma.shape
+        block_n, block_k = min(256, max(n, 1)), min(128, max(k, 1))
+        n_pad, k_pad = -n % block_n, -k % block_k
+        if n_pad or k_pad:
+            x_over_phi = np.pad(x_over_phi, (0, n_pad))
+            gamma = np.pad(gamma, ((0, n_pad), (0, k_pad)))
+    with span("vds.call"):
+        up = (jnp.asarray(x_over_phi, jnp.float32),
+              jnp.asarray(gamma, jnp.float32))
+        count("h2d_bytes", sum(a.nbytes for a in up))
+        mn, arg = vds_argmin(*up, block_n=block_n, block_k=block_k,
+                             interpret=interpret)
+        mn, arg = np.asarray(mn), np.asarray(arg)
+        count("d2h_bytes", mn.nbytes + arg.nbytes)
+    return mn[:k], arg[:k]

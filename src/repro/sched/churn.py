@@ -19,13 +19,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools as _functools
-import time as _time
 from typing import List, Optional, Sequence
 
 import numpy as np
 
 from repro.core.engine import SWEEP_MECHANISMS
 from repro.core.gamma import gamma_matrix
+from repro.core.trace import Span, Tracer, count, span
 from repro.core.types import Allocation, AllocationProblem
 
 VALID_KINDS = ("arrival", "departure", "degrade", "restore")
@@ -78,6 +78,10 @@ class ChurnRecord:
     accel_hits: int = 0      # accepted Anderson candidates this step
     accel_rejects: int = 0   # safeguard fallbacks this step
     rounds_to_tol: int = 0   # rounds to the TIGHT tol (0 if not reached)
+    # the step's ``churn.step`` root span (core.trace): where the step's
+    # host time went, span by span, with its transfer and compile counters
+    trace: Optional[Span] = dataclasses.field(default=None, repr=False,
+                                              compare=False)
 
 
 #: sweep-based mechanisms the simulator can maintain a fixed point for
@@ -179,10 +183,6 @@ class ChurnSimulator:
                        else np.asarray(initial_active, dtype=bool).copy())
         self.cap_scale = np.ones(k)
         self.x = np.zeros((n, k))
-        self._demands = jnp.asarray(problem.demands, jnp.float32)
-        self._caps = jnp.asarray(problem.capacities, jnp.float32)
-        self._weights = jnp.asarray(problem.weights, jnp.float32)
-        self._elig = jnp.asarray(problem.eligibility, jnp.float32)
         self._resolve = _resolve_fn()
         # buckets are built from the ACTIVE support at construction time:
         # departures only mask bucket slots in place, arrivals of users the
@@ -196,14 +196,21 @@ class ChurnSimulator:
                 "layout='bucketed' needs the per-server sweep; the routed "
                 "headroom fill for global-share mechanisms is one-shot "
                 "global — use layout='dense'")
-        self.layout = ("dense" if routed else resolve_layout(
-            layout, support=(problem.eligibility > 0)
-            & self.active[:, None]))
         self._blayout = None
         self.layout_rebuilds = 0
         self._needs_rebuild = False
-        if self.layout == "bucketed":
-            self._build_buckets()
+        with Tracer("churn.init"):
+            self._demands = jnp.asarray(problem.demands, jnp.float32)
+            self._caps = jnp.asarray(problem.capacities, jnp.float32)
+            self._weights = jnp.asarray(problem.weights, jnp.float32)
+            self._elig = jnp.asarray(problem.eligibility, jnp.float32)
+            count("h2d_bytes", sum(a.nbytes for a in (
+                self._demands, self._caps, self._weights, self._elig)))
+            self.layout = ("dense" if routed else resolve_layout(
+                layout, support=(problem.eligibility > 0)
+                & self.active[:, None]))
+            if self.layout == "bucketed":
+                self._build_buckets()
         # persistent lexmm router (global-share + placement="lexmm" ticks):
         # built lazily on the BASE capacities; degrade/restore re-scale its
         # rhs in place, arrivals/departures flow in as activity deltas
@@ -220,6 +227,7 @@ class ChurnSimulator:
         self._covered = self.active.copy()     # users the layout has slots for
         self._idx_j = jnp.asarray(self._blayout.indices)
         self._mask_j = jnp.asarray(self._blayout.mask)
+        count("h2d_bytes", self._idx_j.nbytes + self._mask_j.nbytes)
         self._needs_rebuild = False
 
     # -- event application --------------------------------------------------
@@ -239,25 +247,35 @@ class ChurnSimulator:
             self.cap_scale[ev.server] = 1.0
 
     def _solve(self, x0) -> tuple[np.ndarray, int, float, int, int]:
+        import jax
         import jax.numpy as jnp
         if (self.placement == "lexmm"
                 and self.mechanism not in ("psdsf-rdm", "psdsf-tdm")):
             return self._solve_lexmm_host()
-        out = self._resolve(
-            self._demands, self._caps, self._weights, self._elig,
-            jnp.asarray(self.active), jnp.asarray(self.cap_scale, jnp.float32),
-            None if x0 is None else jnp.asarray(x0, jnp.float32),
-            mechanism=self.mechanism, max_rounds=self.max_rounds,
-            tol=self.tol, placement=self.placement, fill=self.fill,
-            round=self.round, layout=self.layout,
-            buckets=(None if self._blayout is None
-                     else (self._idx_j, self._mask_j)),
-            accel=self.accel)
-        x, rounds, resid = out[0], out[1], out[2]
-        hits, rejects = ((int(out[3]), int(out[4]))
-                         if self.accel == "anderson" else (0, 0))
-        return (np.array(x, dtype=np.float64), int(rounds), float(resid),
-                hits, rejects)
+        with span("churn.solve.upload"):
+            up = (jnp.asarray(self.active),
+                  jnp.asarray(self.cap_scale, jnp.float32),
+                  None if x0 is None else jnp.asarray(x0, jnp.float32))
+            count("h2d_bytes", sum(a.nbytes for a in up if a is not None))
+        # the host blocks here rather than in the download below, so the
+        # download span times the copy alone
+        with span("churn.solve.wait"):
+            out = jax.block_until_ready(self._resolve(
+                self._demands, self._caps, self._weights, self._elig, *up,
+                mechanism=self.mechanism, max_rounds=self.max_rounds,
+                tol=self.tol, placement=self.placement, fill=self.fill,
+                round=self.round, layout=self.layout,
+                buckets=(None if self._blayout is None
+                         else (self._idx_j, self._mask_j)),
+                accel=self.accel))
+        with span("churn.solve.download"):
+            n_out = 5 if self.accel == "anderson" else 3
+            x = np.array(out[0], dtype=np.float64)
+            rounds, resid = int(out[1]), float(out[2])
+            hits, rejects = ((int(out[3]), int(out[4]))
+                             if n_out == 5 else (0, 0))
+            count("d2h_bytes", sum(a.nbytes for a in out[:n_out]))
+        return x, rounds, resid, hits, rejects
 
     def _solve_lexmm_host(self) -> tuple[np.ndarray, int, float, int, int]:
         """Exact flow-routed re-solve for the global-share mechanisms: the
@@ -293,26 +311,35 @@ class ChurnSimulator:
 
     def step(self, events: Sequence[ChurnEvent], time_now: float
              ) -> ChurnRecord:
-        """Apply simultaneous events, re-solve, record telemetry."""
-        for ev in events:
-            self._apply(ev)
-        rebuilds = 0
-        if self._needs_rebuild:
-            # an arrival outside the layout: rebuild from the new active
-            # support (a new Bmax recompiles the jitted sweep — loud by
-            # design, and counted so streams can budget for it)
-            self._build_buckets()
-            self.layout_rebuilds += 1
-            rebuilds = 1
+        """Apply simultaneous events, re-solve, record telemetry. The
+        step runs under a ``churn.step`` root span (``core.trace``), which
+        the record carries as ``trace``."""
+        with Tracer("churn.step") as tr:
+            return self._step(events, time_now, tr.top)
+
+    def _step(self, events: Sequence[ChurnEvent], time_now: float,
+              trace: Span) -> ChurnRecord:
+        with span("churn.apply"):
+            for ev in events:
+                self._apply(ev)
+            rebuilds = 0
+            if self._needs_rebuild:
+                # an arrival outside the layout: rebuild from the new active
+                # support (a new Bmax recompiles the jitted sweep — loud by
+                # design, and counted so streams can budget for it)
+                with span("churn.rebuild"):
+                    self._build_buckets()
+                self.layout_rebuilds += 1
+                rebuilds = 1
         self._router_stats = None
-        t0 = _time.perf_counter()
-        x, rounds, resid, hits, rejects = self._solve(
-            self.x if self.warm_start else None)
-        solve_ms = (_time.perf_counter() - t0) * 1e3
+        with span("churn.solve") as solve_span:
+            x, rounds, resid, hits, rejects = self._solve(
+                self.x if self.warm_start else None)
         rs = self._router_stats          # lexmm ticks only, else None
         cold_rounds = -1
         if self.compare_cold and self.warm_start:
-            _, cold_rounds, *_ = self._solve(None)
+            with span("churn.cold"):
+                _, cold_rounds, *_ = self._solve(None)
         self.x = x
         mn, arg = (self._min_vds() if self.telemetry else (np.inf, -1))
         from repro.core.placement import fill_iter_budget
@@ -326,16 +353,17 @@ class ChurnSimulator:
         # tight-tol certification against the same active-gamma scale the
         # traced sweep accepts on (routed/lexmm ticks are one-shot exact)
         if swept:
-            g_act = np.where(self.active[:, None],
-                             gamma_matrix(self._effective_problem()), 0.0)
-            tight = resid <= self.tol * float(g_act.max(initial=1.0))
+            with span("churn.certify"):
+                g_act = np.where(self.active[:, None],
+                                 gamma_matrix(self._effective_problem()), 0.0)
+                tight = resid <= self.tol * float(g_act.max(initial=1.0))
         else:
             tight = resid == 0.0
         return ChurnRecord(
             time=time_now, n_events=len(events), rounds=rounds,
             cold_rounds=cold_rounds, residual=resid,
             active_users=int(self.active.sum()),
-            total_tasks=float(self.x.sum()), solve_ms=solve_ms,
+            total_tasks=float(self.x.sum()), solve_ms=solve_span.ms,
             min_vds=float(mn), bottleneck_server=int(arg),
             lp_calls=0 if rs is None else rs.lp_calls,
             warm_hits=0 if rs is None else rs.warm_hits,
@@ -349,7 +377,7 @@ class ChurnSimulator:
             layout_rebuilds=rebuilds,
             accel=self.accel if swept else "none",
             accel_hits=hits, accel_rejects=rejects,
-            rounds_to_tol=rounds if tight else 0)
+            rounds_to_tol=rounds if tight else 0, trace=trace)
 
     def run(self, events: Sequence[ChurnEvent]) -> List[ChurnRecord]:
         """Consume a whole stream: batch same-timestamp events, one re-solve
@@ -368,11 +396,13 @@ class ChurnSimulator:
     def _min_vds(self) -> tuple[float, int]:
         from repro.core.dynamic import min_vds_guarded
 
-        g = gamma_matrix(self._effective_problem())
-        mn, _ = min_vds_guarded(self.x, self.problem.weights, g,
-                                 self.active)
-        i = int(np.argmin(mn))
-        return float(mn[i]), i
+        with span("churn.telemetry"):
+            with span("churn.telemetry.gamma"):
+                g = gamma_matrix(self._effective_problem())
+            mn, _ = min_vds_guarded(self.x, self.problem.weights, g,
+                                     self.active)
+            i = int(np.argmin(mn))
+            return float(mn[i]), i
 
     def _effective_problem(self) -> AllocationProblem:
         return AllocationProblem(
