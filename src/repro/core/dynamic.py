@@ -125,28 +125,22 @@ def min_vds_guarded(x: np.ndarray, weights: np.ndarray, gamma: np.ndarray,
     BEFORE the division: a zero-weight user (weights are validated > 0 at
     construction, but callers can rescale the array in place) must be
     excluded exactly like an inactive one, not turn a server's min into
-    inf/NaN. Shared by ``DistributedPSDSF.min_vds`` and the churn
-    simulator's telemetry (imported from here as public API).
+    inf/NaN. Used by ``DistributedPSDSF.min_vds`` on its host gamma (the
+    churn simulator builds the same inputs on the device instead,
+    ``ChurnSimulator._min_vds``).
 
-    The backend decides how the Pallas kernel runs: compiled on ``tpu``,
-    the Pallas interpreter on ``cpu`` (tests); any other platform raises
-    rather than silently interpreting on a device."""
-    import jax
+    The backend decides how the Pallas kernel runs
+    (``psdsf_vds.ops._vds_interpret``)."""
+    from repro.kernels.psdsf_vds.ops import _vds_interpret, min_vds_padded
 
-    from repro.kernels.psdsf_vds.ops import min_vds_padded
-
-    backend = jax.default_backend()
-    if backend not in ("tpu", "cpu"):
-        raise RuntimeError(
-            f"the psdsf_vds kernel runs compiled on tpu or interpreted on "
-            f"cpu; backend {backend!r} has neither")
+    interpret = _vds_interpret()
     with span("vds.prep"):
         mask = np.asarray(active, dtype=bool) & (weights > 0)
         with np.errstate(divide="ignore", invalid="ignore"):
             x_over_phi = np.where(mask, x.sum(axis=1)
                                   / np.where(mask, weights, 1.0), 0.0)
         gamma = np.where(mask[:, None], gamma, 0.0)
-    return min_vds_padded(x_over_phi, gamma, interpret=backend == "cpu")
+    return min_vds_padded(x_over_phi, gamma, interpret=interpret)
 
 
 class DistributedPSDSF:

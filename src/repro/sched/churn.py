@@ -19,12 +19,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools as _functools
-from typing import List, Optional, Sequence
+from typing import Any, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from repro.core.engine import SWEEP_MECHANISMS
-from repro.core.gamma import gamma_matrix
 from repro.core.trace import Span, Tracer, count, span
 from repro.core.types import Allocation, AllocationProblem
 
@@ -82,6 +81,15 @@ class ChurnRecord:
     # host time went, span by span, with its transfer and compile counters
     trace: Optional[Span] = dataclasses.field(default=None, repr=False,
                                               compare=False)
+
+
+class _Resolved(NamedTuple):
+    """What one jitted resolve left on the device, and its certificate
+    scale (read back with its output)."""
+    active: Any              # (N,) bool, as uploaded
+    cap_scale: Any           # (K,) float32, as uploaded
+    x: Any                   # (N, K) allocation, before its download
+    scale: float             # max(1, largest active gamma)
 
 
 #: sweep-based mechanisms the simulator can maintain a fixed point for
@@ -216,6 +224,7 @@ class ChurnSimulator:
         # rhs in place, arrivals/departures flow in as activity deltas
         self._lexmm_router = None
         self._router_stats = None
+        self._resolved: Optional[_Resolved] = None
 
     def _build_buckets(self) -> None:
         import jax.numpy as jnp
@@ -247,6 +256,10 @@ class ChurnSimulator:
             self.cap_scale[ev.server] = 1.0
 
     def _solve(self, x0) -> tuple[np.ndarray, int, float, int, int]:
+        """Re-solve from ``x0`` (None: cold). The jitted resolve also leaves
+        what it holds on the device in ``self._resolved``: the uploaded
+        activity and degrade scales, the allocation before its download, and
+        the certificate's scale."""
         import jax
         import jax.numpy as jnp
         if (self.placement == "lexmm"
@@ -269,12 +282,13 @@ class ChurnSimulator:
                          else (self._idx_j, self._mask_j)),
                 accel=self.accel))
         with span("churn.solve.download"):
-            n_out = 5 if self.accel == "anderson" else 3
             x = np.array(out[0], dtype=np.float64)
             rounds, resid = int(out[1]), float(out[2])
             hits, rejects = ((int(out[3]), int(out[4]))
-                             if n_out == 5 else (0, 0))
-            count("d2h_bytes", sum(a.nbytes for a in out[:n_out]))
+                             if self.accel == "anderson" else (0, 0))
+            scale = float(out[-1])
+            count("d2h_bytes", sum(a.nbytes for a in out))
+        self._resolved = _Resolved(*up[:2], out[0], scale)
         return x, rounds, resid, hits, rejects
 
     def _solve_lexmm_host(self) -> tuple[np.ndarray, int, float, int, int]:
@@ -331,17 +345,19 @@ class ChurnSimulator:
                     self._build_buckets()
                 self.layout_rebuilds += 1
                 rebuilds = 1
-        self._router_stats = None
+        self._router_stats = self._resolved = None
         with span("churn.solve") as solve_span:
             x, rounds, resid, hits, rejects = self._solve(
                 self.x if self.warm_start else None)
         rs = self._router_stats          # lexmm ticks only, else None
+        resolved = self._resolved        # jitted resolve ticks, else None
         cold_rounds = -1
         if self.compare_cold and self.warm_start:
             with span("churn.cold"):
                 _, cold_rounds, *_ = self._solve(None)
         self.x = x
-        mn, arg = (self._min_vds() if self.telemetry else (np.inf, -1))
+        mn, arg = (self._min_vds(resolved) if self.telemetry
+                   else (np.inf, -1))
         from repro.core.placement import fill_iter_budget
 
         psdsf = self.mechanism in ("psdsf-rdm", "psdsf-tdm")
@@ -350,13 +366,14 @@ class ChurnSimulator:
             self.problem.num_resources,
             "tdm" if self.mechanism == "psdsf-tdm" else "rdm", self.fill)
             if swept else 0)
-        # tight-tol certification against the same active-gamma scale the
-        # traced sweep accepts on (routed/lexmm ticks are one-shot exact)
+        # tight-tol certification on the active-gamma scale the resolve
+        # accepted on (routed/lexmm ticks are one-shot exact); a ``_solve``
+        # that bypassed the resolve leaves none, and the scale's floor of 1
+        # is the strictest
         if swept:
             with span("churn.certify"):
-                g_act = np.where(self.active[:, None],
-                                 gamma_matrix(self._effective_problem()), 0.0)
-                tight = resid <= self.tol * float(g_act.max(initial=1.0))
+                scale = 1.0 if resolved is None else resolved.scale
+                tight = resid <= self.tol * scale
         else:
             tight = resid == 0.0
         return ChurnRecord(
@@ -393,15 +410,35 @@ class ChurnSimulator:
         return records
 
     # -- telemetry ----------------------------------------------------------
-    def _min_vds(self) -> tuple[float, int]:
-        from repro.core.dynamic import min_vds_guarded
+    def _min_vds(self, resolved: Optional[_Resolved]) -> tuple[float, int]:
+        """Global min normalized VDS (Eq. 16) and the server attaining it.
+        The kernel's inputs are built on the device from the state the
+        resolve left there (``resolved``; a host-solved tick uploads its
+        activity, degrade scales and allocation instead), and only the
+        per-server minima come back."""
+        import jax.numpy as jnp
+
+        from repro.kernels.psdsf_vds.ops import (_vds_blocks, _vds_interpret,
+                                                 vds_argmin)
 
         with span("churn.telemetry"):
-            with span("churn.telemetry.gamma"):
-                g = gamma_matrix(self._effective_problem())
-            mn, _ = min_vds_guarded(self.x, self.problem.weights, g,
-                                     self.active)
-            i = int(np.argmin(mn))
+            if resolved is None:
+                state = (jnp.asarray(self.active),
+                         jnp.asarray(self.cap_scale, jnp.float32),
+                         jnp.asarray(self.x, jnp.float32))
+                count("h2d_bytes", sum(a.nbytes for a in state))
+            else:
+                state = resolved[:3]
+            inputs = _eq16_inputs_fn()(
+                self._demands, self._caps, self._weights, self._elig, *state)
+            k = self.problem.num_servers
+            block_n, block_k = _vds_blocks(self.problem.num_users, k)
+            with span("vds.call"):
+                mn, _ = vds_argmin(*inputs, block_n=block_n, block_k=block_k,
+                                   interpret=_vds_interpret())
+                mn = np.asarray(mn)
+                count("d2h_bytes", mn.nbytes)
+            i = int(np.argmin(mn[:k]))
             return float(mn[i]), i
 
     def _effective_problem(self) -> AllocationProblem:
@@ -419,9 +456,11 @@ class ChurnSimulator:
 def _resolve_fn():
     """Jitted: effective capacities -> level-rate matrix for the chosen
     mechanism -> warm-started sweep (or the routed/repacked placement
-    mirrors when ``placement="headroom"``). Cached so all simulator
-    instances share one jit cache (one compilation per (mechanism,
-    placement, shapes))."""
+    mirrors when ``placement="headroom"``). Returns the sweep's
+    ``(x, rounds, residual)``, with ``accel_hits, accel_rejects`` under
+    ``accel="anderson"``, and last the certificate's scale. Cached so all
+    simulator instances share one jit cache (one compilation per
+    (mechanism, placement, shapes))."""
     import functools
 
     import jax.numpy as jnp
@@ -444,6 +483,11 @@ def _resolve_fn():
         caps_eff = capacities * cap_scale[:, None]
         g = gamma_matrix_jnp(demands, caps_eff, eligibility)
         g = jnp.where(active[:, None], g, 0.0)
+        # the certificate's scale, returned last on every path: the ACTIVE
+        # users' largest per-server gamma, floored at 1 (the baseline level
+        # rates sum gamma over servers — see baselines_jax; and a departed
+        # huge-gamma user must not loosen it)
+        scale = jnp.maximum(1.0, g.max())
         psdsf = mechanism in ("psdsf-rdm", "psdsf-tdm")
         if psdsf:
             lg = g
@@ -468,13 +512,10 @@ def _resolve_fn():
             if accel == "anderson":  # one-shot fill: nothing to accelerate
                 zero = jnp.asarray(0, jnp.int32)
                 out = out + (zero, zero)
-            return out
+            return out + (scale,)
         if x0 is None:
             x0 = jnp.zeros(lg.shape, dtype=demands.dtype)
         x0 = jnp.where(active[:, None], x0, 0.0)
-        # acceptance band always on the ACTIVE users' per-server gamma scale
-        # (the baseline level rates sum gamma over servers — see
-        # baselines_jax; and a departed huge-gamma user must not loosen it)
         if layout == "bucketed":
             # departure-only churn masks bucket slots in place: the layout
             # was built from the active support, so departed users' slots
@@ -482,21 +523,53 @@ def _resolve_fn():
             idx, mask = buckets
             out = _solve_core_bucketed(demands, caps_eff, weights, lg, x0,
                                        idx, mask & active[idx], mode,
-                                       max_rounds, tol, scale=g.max(),
+                                       max_rounds, tol, scale=scale,
                                        fill=fill, round_mode=round,
                                        accel=accel)
         else:
             out = _solve_core(demands, caps_eff, weights, lg, x0, mode,
-                              max_rounds, tol, scale=g.max(), fill=fill,
+                              max_rounds, tol, scale=scale, fill=fill,
                               round_mode=round, accel=accel)
         if placement == "headroom":
             fixed = _repack_refill_core(demands, caps_eff, weights, g,
                                         *out[:3], mode, max_rounds, tol,
                                         fill=fill, round_mode=round)
             out = fixed + out[3:]
-        return out
+        return out + (scale,)
 
     return resolve
+
+
+@_functools.lru_cache(maxsize=1)
+def _eq16_inputs_fn():
+    """Jitted: the Eq. 16 kernel's inputs from device-resident state — the
+    effective gamma, zeroed outside ``active & (weights > 0)``, and
+    ``x_n / phi_n`` (0 there), both padded to the kernel's blocks. Its own
+    program, so the kernel stays one as well (and the gamma read the
+    kernel's roofline counts stays the kernel's). The shapes are the dense
+    (N, K) ones whatever the layout, so a bucket rebuild recompiles
+    nothing here."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.psdsf_jax import gamma_matrix_jnp
+    from repro.kernels.psdsf_vds.ops import _vds_blocks
+
+    @jax.jit
+    def eq16_inputs(demands, capacities, weights, eligibility, active,
+                    cap_scale, x):
+        mask = active & (weights > 0)
+        g = gamma_matrix_jnp(demands, capacities * cap_scale[:, None],
+                             eligibility)
+        g = jnp.where(mask[:, None], g, 0.0)
+        x_over_phi = jnp.where(
+            mask, x.sum(axis=1) / jnp.where(mask, weights, 1.0), 0.0)
+        (n, k), (block_n, block_k) = g.shape, _vds_blocks(*g.shape)
+        n_pad, k_pad = -n % block_n, -k % block_k
+        return (jnp.pad(x_over_phi, (0, n_pad)),
+                jnp.pad(g, ((0, n_pad), (0, k_pad))))
+
+    return eq16_inputs
 
 
 def poisson_churn_events(n_users: int, n_servers: int, horizon: float,

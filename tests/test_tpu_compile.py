@@ -124,3 +124,22 @@ def test_churn_resolve_bucketed_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+
+
+def test_churn_telemetry_inputs_feed_the_kernel(one_chip):
+    """The churn telemetry's device prep compiles to a program of its own
+    whose outputs are the kernel's padded inputs, with no kernel in it."""
+    import jax.numpy as jnp
+
+    from repro.sched.churn import _eq16_inputs_fn
+
+    n, k, r = N_USERS, N_SERVERS, N_RES
+    s = lambda shape, dt=None: _shape(one_chip, shape, dt)  # noqa: E731
+    compiled = _eq16_inputs_fn().lower(
+        s((n, r)), s((k, r)), s((n,)), s((n, k)), s((n,), jnp.bool_),
+        s((k,)), s((n, k))).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    xphi, gamma = compiled.out_info
+    assert xphi.shape == (_pad(n, 256),)
+    assert gamma.shape == (_pad(n, 256), k)
+    assert xphi.dtype == gamma.dtype == jnp.float32

@@ -137,10 +137,16 @@ def test_a_step_holds_the_spans_of_each_part(churn):
         "churn.apply", "churn.solve", "churn.telemetry", "churn.certify"]
     names = _names(rec.trace)
     for name in ("churn.solve.upload", "churn.solve.wait",
-                 "churn.solve.download", "churn.telemetry.gamma",
-                 "vds.prep", "vds.call"):
+                 "churn.solve.download", "vds.call"):
         assert name in names
-    assert names.count("vds.prep") == 2
+    # the telemetry's inputs are built on the device: no host gamma, no
+    # host masking and padding
+    (telemetry,) = [c for c in rec.trace.children
+                    if c.name == "churn.telemetry"]
+    assert [c.name for c in telemetry.children] == ["vds.call"]
+    assert "churn.telemetry.gamma" not in names and "vds.prep" not in names
+    (certify,) = [c for c in rec.trace.children if c.name == "churn.certify"]
+    assert certify.children == []
     assert "churn.rebuild" not in names
     apply = churn["rebuilt"].trace.children[0]
     assert [c.name for c in apply.children] == ["churn.rebuild"]
@@ -164,19 +170,18 @@ def test_transfer_counters_equal_the_bytes_moved(churn):
     init = churn["init"].counters["h2d_bytes"]
     assert init == (f32 * (n * r + k * r + n + n * k)
                     + churn["init_layout_bytes"])
-    n_pad = n + (-n % min(256, n))
     k_pad = k + (-k % min(128, k))
     rounds_bytes = np.dtype(np.int32).itemsize
     for key in ("first", "again"):
         c = churn[key].trace.counters
         # activity (bool), degrade scales and the warm start up; the vds
-        # kernel's x/phi and gamma up, padded to its blocks
-        assert c["h2d_bytes"] == n + f32 * k + f32 * n * k + f32 * (
-            n_pad + n_pad * k_pad)
-        # the allocation, rounds and residual down; the kernel's minima
-        # and argmins down
-        assert c["d2h_bytes"] == f32 * n * k + rounds_bytes + f32 + 2 * (
-            f32 * k_pad)
+        # kernel's inputs are built on the device from these, so nothing
+        # more goes up
+        assert c["h2d_bytes"] == n + f32 * k + f32 * n * k
+        # the allocation, rounds, residual and certificate scale down; the
+        # kernel's minima (padded to its blocks) down
+        assert c["d2h_bytes"] == (f32 * n * k + rounds_bytes + f32 + f32
+                                  + f32 * k_pad)
     rebuilt = churn["rebuilt"].trace.counters["h2d_bytes"]
     assert rebuilt == churn["again"].trace.counters["h2d_bytes"] + (
         sim._idx_j.nbytes + sim._mask_j.nbytes)
