@@ -85,11 +85,23 @@ class ChurnRecord:
 
 class _Resolved(NamedTuple):
     """What one jitted resolve left on the device, and its certificate
-    scale (read back with its output)."""
+    scale (read back with its scalars)."""
     active: Any              # (N,) bool, as uploaded
     cap_scale: Any           # (K,) float32, as uploaded
-    x: Any                   # (N, K) allocation, before its download
+    x: Any                   # (N, K) float32 allocation, on the device
     scale: float             # max(1, largest active gamma)
+    total: float             # x.sum()
+
+
+class _OnDevice(NamedTuple):
+    """An allocation the jitted resolve left on the device, (N, K)
+    float32. ``copy()`` downloads it as a writable float64 host array, the
+    form a host-solved tick's answer has."""
+    array: Any
+
+    def copy(self) -> np.ndarray:
+        """The allocation downloaded, as a new float64 host array."""
+        return np.array(self.array, dtype=np.float64)
 
 
 #: sweep-based mechanisms the simulator can maintain a fixed point for
@@ -135,6 +147,13 @@ class ChurnSimulator:
     an arrival the layout never saw rebuilds it loudly (recompile + the
     per-record ``layout_rebuilds`` flag). "auto" resolves by density of
     the initial active support.
+
+    The allocation stays on the device between steps, as the float32
+    array the jitted resolve returned: the next warm start is that array,
+    a departure zeroes its row inside the resolve, and ``total_tasks`` is
+    summed there. ``x`` downloads it (as float64) on its first read after
+    a step; ``x_reads`` counts those downloads. A global-share lexmm tick
+    solves on the host and keeps its answer there.
     """
 
     def __init__(self, problem: AllocationProblem, mode: Optional[str] = None,
@@ -190,7 +209,10 @@ class ChurnSimulator:
         self.active = (np.ones(n, dtype=bool) if initial_active is None
                        else np.asarray(initial_active, dtype=bool).copy())
         self.cap_scale = np.ones(k)
-        self.x = np.zeros((n, k))
+        self._departed = np.zeros(n, dtype=bool)  # since the last upload
+        self._x_dev = jnp.zeros((n, k), jnp.float32)
+        self._x_host: Optional[np.ndarray] = np.zeros((n, k))
+        self.x_reads = 0
         self._resolve = _resolve_fn()
         # buckets are built from the ACTIVE support at construction time:
         # departures only mask bucket slots in place, arrivals of users the
@@ -246,8 +268,12 @@ class ChurnSimulator:
             if self._blayout is not None and not self._covered[ev.user]:
                 self._needs_rebuild = True
         elif ev.kind == "departure":
+            # the resolve zeroes the row of the warm start, even if the user
+            # arrives again in this batch; ``x`` shows it zeroed already
             self.active[ev.user] = False
-            self.x[ev.user, :] = 0.0
+            self._departed[ev.user] = True
+            if self._x_host is not None:
+                self._x_host[ev.user] = 0.0
         elif ev.kind == "degrade":
             if not 0.0 < ev.scale <= 1.0:
                 raise ValueError(f"degrade scale must be in (0, 1]: {ev.scale}")
@@ -255,41 +281,45 @@ class ChurnSimulator:
         elif ev.kind == "restore":
             self.cap_scale[ev.server] = 1.0
 
-    def _solve(self, x0) -> tuple[np.ndarray, int, float, int, int]:
-        """Re-solve from ``x0`` (None: cold). The jitted resolve also leaves
-        what it holds on the device in ``self._resolved``: the uploaded
-        activity and degrade scales, the allocation before its download, and
-        the certificate's scale."""
+    def _solve(self, x0) -> tuple[Any, int, float, int, int]:
+        """Re-solve from ``x0``, the device allocation (None: cold).
+        Returns the allocation, as ``_OnDevice`` (a host array from a lexmm
+        tick), with rounds, residual and Anderson hits and rejects. The
+        jitted resolve also leaves what it holds on the device in
+        ``self._resolved``: the uploaded activity and degrade scales, the
+        allocation, and the certificate's scale and the allocation's sum,
+        read back with the other scalars."""
         import jax
-        import jax.numpy as jnp
+        departed, self._departed = self._departed, np.zeros_like(
+            self._departed)
         if (self.placement == "lexmm"
                 and self.mechanism not in ("psdsf-rdm", "psdsf-tdm")):
             return self._solve_lexmm_host()
         with span("churn.solve.upload"):
-            up = (jnp.asarray(self.active),
-                  jnp.asarray(self.cap_scale, jnp.float32),
-                  None if x0 is None else jnp.asarray(x0, jnp.float32))
-            count("h2d_bytes", sum(a.nbytes for a in up if a is not None))
+            up = jax.device_put((self.active, departed,
+                                 self.cap_scale.astype(np.float32)))
+            count("h2d_bytes", sum(a.nbytes for a in up))
         # the host blocks here rather than in the download below, so the
         # download span times the copy alone
         with span("churn.solve.wait"):
             out = jax.block_until_ready(self._resolve(
-                self._demands, self._caps, self._weights, self._elig, *up,
+                self._demands, self._caps, self._weights, self._elig, *up, x0,
                 mechanism=self.mechanism, max_rounds=self.max_rounds,
                 tol=self.tol, placement=self.placement, fill=self.fill,
                 round=self.round, layout=self.layout,
                 buckets=(None if self._blayout is None
                          else (self._idx_j, self._mask_j)),
                 accel=self.accel))
+        # the scalars alone come back; the allocation stays on the device
         with span("churn.solve.download"):
-            x = np.array(out[0], dtype=np.float64)
-            rounds, resid = int(out[1]), float(out[2])
-            hits, rejects = ((int(out[3]), int(out[4]))
-                             if self.accel == "anderson" else (0, 0))
-            scale = float(out[-1])
-            count("d2h_bytes", sum(a.nbytes for a in out))
-        self._resolved = _Resolved(*up[:2], out[0], scale)
-        return x, rounds, resid, hits, rejects
+            got = jax.device_get(out[1:])
+            count("d2h_bytes", sum(a.nbytes for a in out[1:]))
+        rounds, resid = int(got[0]), float(got[1])
+        hits, rejects = ((int(got[2]), int(got[3]))
+                         if self.accel == "anderson" else (0, 0))
+        self._resolved = _Resolved(up[0], up[2], out[0], float(got[-1]),
+                                   float(got[-2]))
+        return _OnDevice(out[0]), rounds, resid, hits, rejects
 
     def _solve_lexmm_host(self) -> tuple[np.ndarray, int, float, int, int]:
         """Exact flow-routed re-solve for the global-share mechanisms: the
@@ -348,14 +378,19 @@ class ChurnSimulator:
         self._router_stats = self._resolved = None
         with span("churn.solve") as solve_span:
             x, rounds, resid, hits, rejects = self._solve(
-                self.x if self.warm_start else None)
+                self._x_dev if self.warm_start else None)
         rs = self._router_stats          # lexmm ticks only, else None
         resolved = self._resolved        # jitted resolve ticks, else None
         cold_rounds = -1
         if self.compare_cold and self.warm_start:
             with span("churn.cold"):
                 _, cold_rounds, *_ = self._solve(None)
-        self.x = x
+        if isinstance(x, _OnDevice):
+            self._x_dev, self._x_host = x.array, None
+            total = resolved.total
+        else:                            # an answer on the host
+            self._x_dev, self._x_host = None, x
+            total = float(x.sum())
         mn, arg = (self._min_vds(resolved) if self.telemetry
                    else (np.inf, -1))
         from repro.core.placement import fill_iter_budget
@@ -380,7 +415,7 @@ class ChurnSimulator:
             time=time_now, n_events=len(events), rounds=rounds,
             cold_rounds=cold_rounds, residual=resid,
             active_users=int(self.active.sum()),
-            total_tasks=float(self.x.sum()), solve_ms=solve_span.ms,
+            total_tasks=total, solve_ms=solve_span.ms,
             min_vds=float(mn), bottleneck_server=int(arg),
             lp_calls=0 if rs is None else rs.lp_calls,
             warm_hits=0 if rs is None else rs.warm_hits,
@@ -441,6 +476,23 @@ class ChurnSimulator:
             i = int(np.argmin(mn[:k]))
             return float(mn[i]), i
 
+    @property
+    def x(self) -> np.ndarray:
+        """The current allocation, (N, K) float64, as a read-only view: the
+        float32 device copy widened, downloaded on the first read after a
+        step (under its own ``churn.x.read`` span) and kept until the
+        next; rows of users departed since are zero."""
+        if self._x_host is None:
+            with Tracer("churn.x.read"):
+                x = _OnDevice(self._x_dev).copy()
+                count("d2h_bytes", self._x_dev.nbytes)
+            x[self._departed] = 0.0
+            self._x_host = x
+            self.x_reads += 1
+        view = self._x_host.view()
+        view.flags.writeable = False
+        return view
+
     def _effective_problem(self) -> AllocationProblem:
         return AllocationProblem(
             self.problem.demands,
@@ -456,9 +508,11 @@ class ChurnSimulator:
 def _resolve_fn():
     """Jitted: effective capacities -> level-rate matrix for the chosen
     mechanism -> warm-started sweep (or the routed/repacked placement
-    mirrors when ``placement="headroom"``). Returns the sweep's
-    ``(x, rounds, residual)``, with ``accel_hits, accel_rejects`` under
-    ``accel="anderson"``, and last the certificate's scale. Cached so all
+    mirrors when ``placement="headroom"``). ``x0`` (None: cold) is zeroed
+    for users not ``active`` or ``departed`` since it was computed. Returns
+    the sweep's ``(x, rounds, residual)``, with ``accel_hits,
+    accel_rejects`` under ``accel="anderson"``, then ``x.sum()`` and last
+    the certificate's scale. Cached so all
     simulator instances share one jit cache (one compilation per
     (mechanism, placement, shapes))."""
     import functools
@@ -475,10 +529,10 @@ def _resolve_fn():
     @functools.partial(jax.jit, static_argnames=("mechanism", "max_rounds",
                                                  "placement", "fill",
                                                  "round", "layout", "accel"))
-    def resolve(demands, capacities, weights, eligibility, active, cap_scale,
-                x0, *, mechanism, max_rounds, tol, placement="level",
-                fill="event", round="gauss", layout="dense", buckets=None,
-                accel="none"):
+    def resolve(demands, capacities, weights, eligibility, active, departed,
+                cap_scale, x0, *, mechanism, max_rounds, tol,
+                placement="level", fill="event", round="gauss",
+                layout="dense", buckets=None, accel="none"):
         _check_accel(accel)
         caps_eff = capacities * cap_scale[:, None]
         g = gamma_matrix_jnp(demands, caps_eff, eligibility)
@@ -512,10 +566,10 @@ def _resolve_fn():
             if accel == "anderson":  # one-shot fill: nothing to accelerate
                 zero = jnp.asarray(0, jnp.int32)
                 out = out + (zero, zero)
-            return out + (scale,)
+            return out + (out[0].sum(), scale)
         if x0 is None:
             x0 = jnp.zeros(lg.shape, dtype=demands.dtype)
-        x0 = jnp.where(active[:, None], x0, 0.0)
+        x0 = jnp.where((active & ~departed)[:, None], x0, 0.0)
         if layout == "bucketed":
             # departure-only churn masks bucket slots in place: the layout
             # was built from the active support, so departed users' slots
@@ -535,7 +589,7 @@ def _resolve_fn():
                                         *out[:3], mode, max_rounds, tol,
                                         fill=fill, round_mode=round)
             out = fixed + out[3:]
-        return out + (scale,)
+        return out + (out[0].sum(), scale)
 
     return resolve
 

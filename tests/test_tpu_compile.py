@@ -115,7 +115,7 @@ def test_churn_resolve_bucketed_fits_one_chip(one_chip):
     s = lambda shape, dt=None: _shape(one_chip, shape, dt)  # noqa: E731
     compiled = _resolve_fn().lower(
         s((n, r)), s((k, r)), s((n,)), s((n, k)), s((n,), jnp.bool_),
-        s((k,)), s((n, k)), mechanism="psdsf-rdm",
+        s((n,), jnp.bool_), s((k,)), s((n, k)), mechanism="psdsf-rdm",
         max_rounds=max_rounds, tol=s(()),
         placement="level", fill="event", round="gauss", layout="bucketed",
         buckets=(s((k, b), jnp.int32), s((k, b), jnp.bool_)),

@@ -174,13 +174,14 @@ def test_transfer_counters_equal_the_bytes_moved(churn):
     rounds_bytes = np.dtype(np.int32).itemsize
     for key in ("first", "again"):
         c = churn[key].trace.counters
-        # activity (bool), degrade scales and the warm start up; the vds
-        # kernel's inputs are built on the device from these, so nothing
-        # more goes up
-        assert c["h2d_bytes"] == n + f32 * k + f32 * n * k
-        # the allocation, rounds, residual and certificate scale down; the
-        # kernel's minima (padded to its blocks) down
-        assert c["d2h_bytes"] == (f32 * n * k + rounds_bytes + f32 + f32
+        # activity and departures (bool) and degrade scales up; the warm
+        # start is the allocation already on the device, and the vds
+        # kernel's inputs are built there, so nothing more goes up
+        assert c["h2d_bytes"] == n + n + f32 * k
+        # rounds, residual, the allocation's sum and the certificate scale
+        # down, and the kernel's minima (padded to its blocks); the
+        # allocation stays on the device
+        assert c["d2h_bytes"] == (rounds_bytes + f32 + f32 + f32
                                   + f32 * k_pad)
     rebuilt = churn["rebuilt"].trace.counters["h2d_bytes"]
     assert rebuilt == churn["again"].trace.counters["h2d_bytes"] + (
@@ -212,3 +213,26 @@ def test_a_profiler_trace_holds_the_step_on_the_host_plane(churn, tmp_path):
                 names.update(e.name for e in line.events)
     assert {"repro.churn.step", "repro.churn.solve",
             "repro.vds.call"} <= names
+
+
+def test_the_allocation_downloads_once_on_its_first_read(churn):
+    sim = churn["sim"]
+    n, k = USERS, SERVERS
+    reads = sim.x_reads
+    before = len(trace.recent("churn.x.read"))
+    rec = sim.step([ChurnEvent(4.0, "departure", user=9)], 4.0)
+    # a step whose allocation is never read downloads none of it
+    assert len(trace.recent("churn.x.read")) == before
+    assert sim.x_reads == reads
+    assert "churn.x.read" not in _names(rec.trace)
+    x = sim.x
+    assert np.shares_memory(sim.x, x)
+    assert sim.x_reads == reads + 1
+    roots = trace.recent("churn.x.read")
+    assert len(roots) == before + 1
+    assert roots[-1].parent is None
+    assert roots[-1].counters["d2h_bytes"] == 4 * n * k
+    assert trace.recent("churn.step")[-1] is rec.trace
+    assert x.dtype == np.float64 and not x.flags.writeable
+    np.testing.assert_array_equal(x, np.asarray(sim._x_dev, np.float64))
+    assert not x[9].any() and x.sum() > 0
