@@ -579,8 +579,8 @@ def fill_comparison():
 
 
 def sparse_scale():
-    """Sparse-eligibility bucketed engine vs the dense engine (the PR-8
-    tentpole's perf rows) on the pinned datacenter instance — the
+    """The sparse bucketed layout vs the full layout (``buckets=None``)
+    through the one jitted sweep core, on the pinned datacenter instance — the
     ``sparse_cell_instance`` defaults: ~20k users x 256 servers at ~3%
     eligibility density, f64, ``fill="bisect"``, ``tol=0.0`` + a fixed
     8-round budget so both layouts execute identical rounds and the parity
@@ -621,7 +621,7 @@ def sparse_scale():
             def run(layout=layout):
                 return jax.block_until_ready(psdsf_solve_jax(
                     *args, mode="rdm", max_rounds=8, tol=0.0,
-                    fill="bisect", layout=layout,
+                    fill="bisect",
                     buckets=buckets if layout == "bucketed" else None))
             us, (x, rounds, resid) = _t(run, repeat=2)
             results[layout] = (us, np.asarray(x), int(rounds),
@@ -663,9 +663,12 @@ def convergence_comparison():
     Three instance rows, one claim each:
 
       * ``convcmp_dense_*`` / ``convcmp_cell_*`` — the dense 60x12 and
-        cell 256x32 instances LIMIT-CYCLE at tol=1e-5: the plain sweep
-        burns its whole round budget without certifying while Anderson
-        certifies in <= half the budget. The anderson row self-certifies
+        cell 256x32 instances, chosen because the plain sweep burned its
+        whole round budget there at tol=1e-5. That was its damping
+        schedule crawling, not a limit cycle: since the jitted sweep lifts
+        the damping on a slow monotone approach, the plain rows certify
+        (85 and 84 rounds) and Anderson's (65 and 53) no longer halve
+        them. The anderson row self-certifies
         ``round_ratio=`` (vs the plain rounds, same process) and
         ``cert=`` (1 iff resid <= tol * gamma-scale);
         ``benchmarks/check_perf.py`` gates ratio <= 0.5 AND cert=1.
@@ -721,7 +724,7 @@ def convergence_comparison():
         pair("cell", cell, 1e-5, 256)
         sparse, _ = sparse_cell_instance()
         lay = BucketedLayout.from_support(gamma_matrix(sparse) > 0)
-        pair("sparse", sparse, 1e-5, 48, fill="bisect", layout="bucketed",
+        pair("sparse", sparse, 1e-5, 48, fill="bisect",
              buckets=(jnp.asarray(lay.indices), jnp.asarray(lay.mask)),
              note=" (converges plainly: safeguard overhead, ungated)")
         # parity on a converging instance: the accelerated fixed point IS

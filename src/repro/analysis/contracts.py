@@ -41,6 +41,7 @@ _F64 = "n/a — float64 end-to-end; precision is a DistributedPSDSF tick knob"
 _IS_JAX = "n/a — this IS the jax backend; backend dispatch is engine.solve"
 _IS_NUMPY = ("n/a — numpy implementation; backend dispatch lives in "
              "engine.solve")
+_FULL_LAYOUT = "n/a — `buckets=None` is the full layout"
 
 ENTRY_POINTS = {
     ("src/repro/core/engine.py", "solve"): {
@@ -138,7 +139,7 @@ ENTRY_POINTS = {
         "placement": dict(forward=True),
         "fill": dict(forward=True),
         "round": dict(forward=True),
-        "layout": dict(forward=True),
+        "layout": _FULL_LAYOUT,
         "precision": "n/a — dtype follows the input arrays (_solve_dtype); "
                      "there is no precision knob on the batch solves",
         "accel": dict(forward=True),
@@ -149,7 +150,7 @@ ENTRY_POINTS = {
         "placement": dict(forward=True),
         "fill": dict(forward=True),
         "round": dict(forward=True),
-        "layout": dict(forward=True),
+        "layout": _FULL_LAYOUT,
         "precision": "n/a — dtype follows the input arrays (_solve_dtype)",
         "accel": dict(forward=True),
     },
@@ -159,7 +160,7 @@ ENTRY_POINTS = {
         "placement": dict(forward=True),
         "fill": dict(forward=True),
         "round": dict(forward=True),
-        "layout": dict(forward=True),
+        "layout": _FULL_LAYOUT,
         "precision": "n/a — dtype follows the input arrays (_solve_dtype)",
         "accel": dict(forward=True),
     },
@@ -170,7 +171,7 @@ ENTRY_POINTS = {
         "placement": dict(forward=True),
         "fill": dict(forward=True),
         "round": dict(forward=True),
-        "layout": dict(forward=True),
+        "layout": _FULL_LAYOUT,
         "precision": "n/a — dtype follows the input arrays (_solve_dtype)",
         "accel": dict(forward=True),
     },
@@ -181,7 +182,7 @@ ENTRY_POINTS = {
         "placement": dict(forward=True),
         "fill": dict(forward=True),
         "round": dict(forward=True),
-        "layout": dict(forward=True),
+        "layout": _FULL_LAYOUT,
         "precision": "n/a — dtype follows the input arrays (_solve_dtype)",
         "accel": dict(forward=True),
     },
@@ -293,7 +294,7 @@ JIT_PURITY = dict(
     #: trace-time gates: host-side validation helpers that run during
     #: tracing on static (non-traced) arguments; excluded from the closure
     trace_time_gates=frozenset({
-        "_check_placement", "_check_buckets", "_check_accel",
+        "_check_placement", "_check_accel",
         "_reject_lexmm_traced", "get_placement", "min"}),
     #: numpy attributes that are trace-safe constants/dtypes, not ops
     np_const_allow=frozenset({

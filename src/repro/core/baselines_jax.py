@@ -20,8 +20,8 @@ import numpy as np
 
 from .baselines import LEVEL_FILL_MECHANISMS, level_rate_matrix
 from .placement import ROUTED_FILL_CORRECTORS, SolveInfo, stranded_fraction
-from .psdsf_jax import (_BIG, _check_accel, _check_buckets, _check_placement,
-                        _solve_core, _solve_core_bucketed, _solve_dtype,
+from .psdsf_jax import (_BIG, _batch_buckets, _check_accel, _check_placement,
+                        _full_layout, _solve_core, _solve_dtype,
                         gamma_matrix_jnp)
 from .types import Allocation, AllocationProblem
 
@@ -146,6 +146,17 @@ def _routed_fill_core(demands, capacities, weights, level_gamma,
     return x, events, jnp.array(0.0, dtype)
 
 
+def _routed_fill(demands, capacities, weights, level_gamma, accel):
+    """``_routed_fill_core`` with the sweep's return tuple: under
+    ``accel="anderson"`` it appends zero (accel_hits, accel_rejects) — a
+    one-shot fill has nothing to accelerate."""
+    out = _routed_fill_core(demands, capacities, weights, level_gamma)
+    if accel == "anderson":
+        zero = jnp.asarray(0, jnp.int32)
+        out = out + (zero, zero)
+    return out
+
+
 def _reject_lexmm_traced(placement: str) -> None:
     if placement == "lexmm":
         raise ValueError(
@@ -155,119 +166,92 @@ def _reject_lexmm_traced(placement: str) -> None:
             "flowrouter.lexmm_route) or the numpy engine")
 
 
+def _reject_routed_buckets(placement: str, buckets) -> None:
+    """Trace-time gate: the routed headroom fill is not a sweep, so it has
+    no bucketed form."""
+    if placement == "headroom" and buckets is not None:
+        raise ValueError("buckets need the per-server sweep; the routed "
+                         "headroom fill is one-shot global — pass "
+                         "buckets=None")
+
+
 @functools.partial(jax.jit, static_argnames=("max_rounds", "placement",
-                                             "fill", "round", "layout",
-                                             "accel"))
+                                             "fill", "round", "accel"))
 def baseline_solve_jax(demands, capacities, weights, level_gamma, *, x0=None,
                        max_rounds: int = 256, tol: float = 1e-6,
                        placement: str = "level", fill: str = "event",
-                       round: str = "gauss", layout: str = "dense",
-                       buckets=None, accel: str = "none"):
+                       round: str = "gauss", buckets=None,
+                       accel: str = "none"):
     """Solve one exact baseline fill. Returns (x (N,K), rounds, residual).
 
     ``level_gamma`` is the (N, K) level-rate matrix from
     ``level_rate_matrix`` / ``level_rate_matrix_jnp``. Warm-startable via
     ``x0`` exactly like ``psdsf_solve_jax``; ``fill``/``round``/``accel``
-    select the per-server fill engine, outer iteration and outer-iteration
-    accelerator exactly like the PS-DSF entry points (the solver body is
-    shared; ``accel="anderson"`` appends (accel_hits, accel_rejects)).
+    and ``buckets`` (``None``: the full layout) act exactly as on the
+    PS-DSF entry points (the solver body is shared;
+    ``accel="anderson"`` appends (accel_hits, accel_rejects)).
     ``placement="headroom"`` runs the routed global fill instead of the
     per-server sweep (one-shot exact; ``x0``, the sweep knobs and the fill
-    engine are ignored — the accel axis with it); ``"bestfit"`` is
-    numpy-only; ``"lexmm"``'s flow certificates are LP solves with
-    data-dependent pivoting — there is nothing to trace, so this jitted
-    entry point rejects it (``solve_baseline_jax`` routes it host-side
-    instead).
+    engine are ignored — the accel axis with it — and ``buckets`` must be
+    ``None``); ``"bestfit"`` is numpy-only; ``"lexmm"``'s flow
+    certificates are LP solves with data-dependent pivoting — there is
+    nothing to trace, so this jitted entry point rejects it
+    (``solve_baseline_jax`` routes it host-side instead).
     """
     _check_placement(placement)
     _reject_lexmm_traced(placement)
-    _check_buckets(layout, buckets)
+    _reject_routed_buckets(placement, buckets)
     _check_accel(accel)
     if placement == "headroom":
-        if layout == "bucketed":
-            raise ValueError("layout='bucketed' needs the per-server sweep; "
-                             "the routed headroom fill is one-shot global — "
-                             "use layout='dense'")
-        out = _routed_fill_core(demands, capacities, weights, level_gamma)
-        if accel == "anderson":     # one-shot fill: nothing to accelerate
-            zero = jnp.asarray(0, jnp.int32)
-            out = out + (zero, zero)
-        return out
+        return _routed_fill(demands, capacities, weights, level_gamma, accel)
     n, k = level_gamma.shape
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((n, k), dtype=dtype)
-    scale = _gamma_scale(demands, capacities, level_gamma)
-    if layout == "bucketed":
-        idx, mask = buckets
-        return _solve_core_bucketed(demands, capacities, weights,
-                                    level_gamma, x0.astype(dtype), idx, mask,
-                                    "rdm", max_rounds, tol, scale=scale,
-                                    fill=fill, round_mode=round, accel=accel)
+    idx, mask = _full_layout(n, k) if buckets is None else buckets
     return _solve_core(demands, capacities, weights, level_gamma,
-                       x0.astype(dtype), "rdm", max_rounds, tol,
-                       scale=scale, fill=fill, round_mode=round, accel=accel)
+                       x0.astype(dtype), idx, mask, "rdm", max_rounds, tol,
+                       scale=_gamma_scale(demands, capacities, level_gamma),
+                       fill=fill, round_mode=round, accel=accel)
 
 
 @functools.partial(jax.jit, static_argnames=("max_rounds", "placement",
-                                             "fill", "round", "layout",
-                                             "accel"))
+                                             "fill", "round", "accel"))
 def baseline_solve_batched(demands, capacities, weights, level_gamma, *,
                            x0=None, max_rounds: int = 256, tol: float = 1e-6,
                            placement: str = "level", fill: str = "event",
-                           round: str = "gauss", layout: str = "dense",
-                           buckets=None, accel: str = "none"):
+                           round: str = "gauss", buckets=None,
+                           accel: str = "none"):
     """Solve B independent baseline fills in one jitted vmap call.
 
     Shapes as ``psdsf_solve_batched``: demands (B, N, R), capacities
     (B, K, R), weights (B, N), level_gamma (B, N, K), optional x0 (B, N, K).
     Pad heterogeneous problems with ``psdsf_jax.batch_problems`` (padding is
     inert: padded users carry level rate 0, padded servers zero capacity).
-    ``placement``/``fill``/``round``/``layout``/``accel`` as in
-    ``baseline_solve_jax``
-    (``"lexmm"`` rejected: the flow certificates solve host-side); bucketed
+    ``placement``/``fill``/``round``/``accel`` as in ``baseline_solve_jax``
+    (``"lexmm"`` rejected: the flow certificates solve host-side);
     ``buckets`` are per-problem (B, K, Bmax) idx/mask stacks as for
-    ``psdsf_solve_batched``.
+    ``psdsf_solve_batched`` (``None``: the full layout).
     """
     _check_placement(placement)
     _reject_lexmm_traced(placement)
-    _check_buckets(layout, buckets)
+    _reject_routed_buckets(placement, buckets)
     _check_accel(accel)
-    if placement == "headroom" and layout == "bucketed":
-        raise ValueError("layout='bucketed' needs the per-server sweep; "
-                         "the routed headroom fill is one-shot global — "
-                         "use layout='dense'")
     b, n, k = level_gamma.shape
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((b, n, k), dtype=dtype)
+    idx, mask = _batch_buckets(buckets, b, n, k)
 
-    if layout == "bucketed":
-        idx, mask = buckets
-
-        def solve_b(d, c, w, lg, x0_, idx_, mask_):
-            return _solve_core_bucketed(d, c, w, lg, x0_, idx_, mask_,
-                                        "rdm", max_rounds, tol,
-                                        scale=_gamma_scale(d, c, lg),
-                                        fill=fill, round_mode=round,
-                                        accel=accel)
-
-        return jax.vmap(solve_b)(demands, capacities, weights, level_gamma,
-                                 x0.astype(dtype), idx, mask)
-
-    def solve(d, c, w, lg, x0_):
+    def solve(d, c, w, lg, x0_, idx_, mask_):
         if placement == "headroom":
-            out = _routed_fill_core(d, c, w, lg)
-            if accel == "anderson":
-                zero = jnp.asarray(0, jnp.int32)
-                out = out + (zero, zero)
-            return out
-        return _solve_core(d, c, w, lg, x0_, "rdm", max_rounds, tol,
-                           scale=_gamma_scale(d, c, lg), fill=fill,
+            return _routed_fill(d, c, w, lg, accel)
+        return _solve_core(d, c, w, lg, x0_, idx_, mask_, "rdm", max_rounds,
+                           tol, scale=_gamma_scale(d, c, lg), fill=fill,
                            round_mode=round, accel=accel)
 
     return jax.vmap(solve)(demands, capacities, weights, level_gamma,
-                           x0.astype(dtype))
+                           x0.astype(dtype), idx, mask)
 
 
 def batch_level_rates(problems, mechanism: str, dtype=np.float32):
@@ -340,7 +324,7 @@ def solve_baseline_jax(problem: AllocationProblem, mechanism: str, x0=None,
         jnp.asarray(problem.weights), jnp.asarray(lg),
         x0=None if x0 is None else jnp.asarray(x0), max_rounds=max_rounds,
         tol=tol, placement=placement, fill=fill, round=round,
-        layout=resolved, buckets=buckets, accel=accel)
+        buckets=buckets, accel=accel)
     x, rounds, resid = out[0], out[1], out[2]
     hits, rejects = (int(out[3]), int(out[4])) if accel == "anderson" \
         else (0, 0)

@@ -13,8 +13,8 @@ Two engines:
 
 * ``engine="numpy"`` — the reference oracle: a pure-Python loop over
   ``psdsf.server_fill_*`` per server. Exact (float64), easy to read, slow.
-* ``engine="jax"`` — one jitted ``lax.fori_loop`` over the selected servers,
-  each iteration running the vectorized fill from ``psdsf_jax``. Identical
+* ``engine="jax"`` — the jitted sweep core's Gauss-Seidel round
+  (``psdsf_jax._gauss_round``) over the selected servers. Identical
   Gauss-Seidel order and math, so the engines agree to fp32 round-off; this
   is what makes 10^3-server ticks at scheduler rates feasible.
 
@@ -42,79 +42,32 @@ _ENGINES = ("numpy", "jax")
 def _tick_jax_fn():
     """Build the jitted tick lazily so importing this module never pulls in
     jax for numpy-engine users; cached so every engine instance shares one
-    jit cache instead of recompiling per instance."""
+    jit cache instead of recompiling per instance.
+
+    The tick is the sweep core's Gauss-Seidel round
+    (``psdsf_jax._gauss_round``) at alpha 1 over ``servers``, with gamma
+    zeroed for inactive users. ``buckets=(idx, mask)`` (a
+    ``layout.BucketedLayout``'s arrays) fills each server over its
+    eligibility bucket — O(nnz) per full tick; ``None`` is the full layout.
+    Either way the dense state round-trips through the bucket
+    gather/scatter exactly (allocations live only on the support)."""
     import jax
     import jax.numpy as jnp
 
-    from .psdsf_jax import (_fill_one_server_rdm, _fill_one_server_rdm_bisect,
-                            _fill_one_server_tdm, _fill_one_server_tdm_bisect)
+    from .psdsf_jax import (_bucket_fill, _full_layout, _gather,
+                            _gauss_round, _scatter)
 
     @functools.partial(jax.jit, static_argnames=("mode", "fill"))
-    def tick(x, demands, capacities, weights, gamma, active, servers, *,
-             mode, fill="event"):
+    def tick(x, demands, capacities, weights, gamma, active, servers,
+             buckets=None, *, mode, fill="event"):
+        n, k = x.shape
+        idx, mask = _full_layout(n, k) if buckets is None else buckets
         gamma = jnp.where(active[:, None], gamma, 0.0)
-
-        def body(j, x):
-            i = servers[j]
-            x_ext = x.sum(axis=1) - x[:, i]
-            if mode == "rdm":
-                f = (_fill_one_server_rdm_bisect if fill == "bisect"
-                     else _fill_one_server_rdm)
-                xi = f(capacities[i], demands, weights, gamma[:, i], x_ext)
-            else:
-                f = (_fill_one_server_tdm_bisect if fill == "bisect"
-                     else _fill_one_server_tdm)
-                xi = f(demands, weights, gamma[:, i], x_ext)
-            return x.at[:, i].set(xi)
-
-        return jax.lax.fori_loop(0, servers.shape[0], body, x)
-
-    return tick
-
-
-@functools.lru_cache(maxsize=1)
-def _tick_jax_bucketed_fn():
-    """Bucketed twin of ``_tick_jax_fn``: each server's fill runs on its
-    pre-gathered (Bmax,)-shaped eligibility bucket and external floors are
-    maintained by O(Bmax) scatter-adds — O(nnz) per full tick instead of
-    O(N*K). The dense state round-trips through the bucket gather/scatter
-    (exact: allocations live only on the support)."""
-    import jax
-    import jax.numpy as jnp
-
-    from .psdsf_jax import (_fill_one_server_rdm, _fill_one_server_rdm_bisect,
-                            _fill_one_server_tdm, _fill_one_server_tdm_bisect)
-
-    @functools.partial(jax.jit, static_argnames=("mode", "fill"))
-    def tick(x, dem_b, capacities, phi_b, gam_b, idx, mask, active,
-             servers, *, mode, fill="event"):
-        k = idx.shape[0]
-        cols = jnp.broadcast_to(jnp.arange(k, dtype=idx.dtype)[:, None],
-                                idx.shape)
-        xb = jnp.where(mask, x[idx, cols], 0.0)
-        xsum = jnp.zeros(x.shape[0], x.dtype).at[idx.ravel()].add(xb.ravel())
-
-        def body(j, carry):
-            xb, xsum = carry
-            i = servers[j]
-            u = idx[i]
-            gi = jnp.where(active[u] & mask[i], gam_b[i], 0.0)
-            x_ext = xsum[u] - xb[i]
-            if mode == "rdm":
-                f = (_fill_one_server_rdm_bisect if fill == "bisect"
-                     else _fill_one_server_rdm)
-                xi = f(capacities[i], dem_b[i], phi_b[i], gi, x_ext)
-            else:
-                f = (_fill_one_server_tdm_bisect if fill == "bisect"
-                     else _fill_one_server_tdm)
-                xi = f(dem_b[i], phi_b[i], gi, x_ext)
-            xi = jnp.where(mask[i], xi, 0.0)
-            return xb.at[i].set(xi), xsum.at[u].add(xi - xb[i])
-
-        xb, _ = jax.lax.fori_loop(0, servers.shape[0], body, (xb, xsum))
-        # scatter-ADD (see psdsf_jax._solve_core_bucketed): masked slots
-        # contribute exact zeros even where padding replicates a user id
-        return jnp.zeros_like(x).at[idx, cols].add(jnp.where(mask, xb, 0.0))
+        fill_server = _bucket_fill(mode, fill, demands, capacities, weights,
+                                   gamma, idx, mask)
+        xb, _ = _gauss_round(fill_server, idx, mask, servers, n,
+                             _gather(x, idx, mask), 1.0)
+        return _scatter(xb, idx, mask, n)
 
     return tick
 
@@ -238,17 +191,10 @@ class DistributedPSDSF:
                 self._caps = jnp.asarray(problem.capacities, dt)
                 self._weights = jnp.asarray(problem.weights, dt)
                 self._gamma = jnp.asarray(self.gamma, dt)
-                if self._blayout is not None:
-                    bl = self._blayout
-                    self._tick_jax_b = _tick_jax_bucketed_fn()
-                    self._idx_j = jnp.asarray(bl.indices)
-                    self._mask_j = jnp.asarray(bl.mask)
-                    self._dem_bj = self._demands[self._idx_j]
-                    self._phi_bj = self._weights[self._idx_j]
-                    self._gam_bj = jnp.asarray(np.where(
-                        bl.mask,
-                        np.take_along_axis(self.gamma.T, bl.indices, axis=1),
-                        0.0), dt)
+                self._buckets_j = (
+                    None if self._blayout is None
+                    else (jnp.asarray(self._blayout.indices),
+                          jnp.asarray(self._blayout.mask)))
 
     def _precision_scope(self):
         import contextlib
@@ -394,18 +340,11 @@ class DistributedPSDSF:
     def _tick_with_jax(self, servers: np.ndarray) -> None:
         import jax.numpy as jnp
         with self._precision_scope():
-            if self._blayout is not None:
-                x = self._tick_jax_b(
-                    jnp.asarray(self.x, self._demands.dtype), self._dem_bj,
-                    self._caps, self._phi_bj, self._gam_bj, self._idx_j,
-                    self._mask_j, jnp.asarray(self.active),
-                    jnp.asarray(servers), mode=self.mode, fill=self.fill)
-            else:
-                x = self._tick_jax(
-                    jnp.asarray(self.x, self._demands.dtype), self._demands,
-                    self._caps, self._weights, self._gamma,
-                    jnp.asarray(self.active), jnp.asarray(servers),
-                    mode=self.mode, fill=self.fill)
+            x = self._tick_jax(
+                jnp.asarray(self.x, self._demands.dtype), self._demands,
+                self._caps, self._weights, self._gamma,
+                jnp.asarray(self.active), jnp.asarray(servers),
+                self._buckets_j, mode=self.mode, fill=self.fill)
             x.block_until_ready()
         self.x = np.array(x, dtype=np.float64)   # copy: keep self.x writable
 

@@ -241,8 +241,8 @@ def _solve_psdsf_via_jax(problem: AllocationProblem, mechanism: str, x0=None,
 
     g = gamma_matrix(problem)
     mode = "rdm" if mechanism == "psdsf-rdm" else "tdm"
-    # "auto" resolves host-side (the jitted entries take a concrete
-    # layout name + pre-built buckets; density inspection can't trace)
+    # "auto" resolves host-side (the jitted entries take pre-built
+    # buckets, None for dense; density inspection can't trace)
     resolved = resolve_layout(layout, support=g)
     buckets = None
     bucket_max = 0
@@ -255,8 +255,7 @@ def _solve_psdsf_via_jax(problem: AllocationProblem, mechanism: str, x0=None,
         jnp.asarray(problem.weights), jnp.asarray(g),
         x0=None if x0 is None else jnp.asarray(x0),
         mode=mode, max_rounds=max_rounds, tol=tol, placement=placement,
-        fill=fill, round=round, layout=resolved, buckets=buckets,
-        accel=accel)
+        fill=fill, round=round, buckets=buckets, accel=accel)
     x, rounds, resid = out[0], out[1], out[2]
     hits, rejects = (int(out[3]), int(out[4])) if accel == "anderson" \
         else (0, 0)

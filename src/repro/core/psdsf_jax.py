@@ -339,7 +339,8 @@ def _anderson_rounds(one_round, x0, max_rounds, tol, scale, alpha0):
         resid = jnp.where(accept, resid_c, resid_p)
         hits = hits + accept.astype(jnp.int32)
         rejects = rejects + reject.astype(jnp.int32)
-        # same alpha-normalized stall schedule as the plain cores
+        # the plain core's alpha-normalized stall schedule, without its
+        # drift test: the mixer extrapolates a repeating step by itself
         norm = resid / alpha
         stall = (rounds >= 3) & (norm > 0.9 * prev_norm) & (alpha > 0.01)
         alpha = jnp.where(stall, alpha * 0.7, alpha)
@@ -364,13 +365,118 @@ def _check_accel(accel: str) -> None:
         raise ValueError(f"accel must be 'none' or 'anderson': {accel!r}")
 
 
-def _solve_core(demands, capacities, weights, gamma, x0, mode, max_rounds,
-                tol, servers=None, alpha0=1.0, scale=None, fill="event",
-                round_mode="gauss", accel="none"):
-    """Traced solver body shared by the single and batched entry points.
+def _server_fill(mode, fill):
+    """The per-server fill engine for ``mode`` x ``fill``, as one signature
+    ``f(cap, demands, phi, gamma_i, x_ext)`` (the TDM fills ignore
+    ``cap``): ``"event"`` is the argsort + saturation-event scan,
+    ``"bisect"`` the sort-free monotone bisection (same fixed point — see
+    ``_fill_one_server_rdm_bisect``)."""
+    if mode not in ("rdm", "tdm"):
+        raise ValueError(f"mode must be 'rdm' or 'tdm': {mode!r}")
+    if fill not in ("event", "bisect"):
+        raise ValueError(f"fill must be 'event' or 'bisect': {fill!r}")
+    bisect = fill == "bisect"
+    if mode == "rdm":
+        return _fill_one_server_rdm_bisect if bisect else _fill_one_server_rdm
+    f = _fill_one_server_tdm_bisect if bisect else _fill_one_server_tdm
+    return lambda cap, demands, phi, gamma_i, x_ext: f(demands, phi, gamma_i,
+                                                       x_ext)
+
+
+def _full_layout(n, k):
+    """The full bucket layout of an (N, K) problem: every server's bucket
+    holds every user, in order — what ``layout.BucketedLayout.from_support``
+    builds from an all-ones support, here built in the trace from the
+    shapes alone. ``buckets=None`` means this layout everywhere.
+
+    The barrier hides that the indices are an iota. XLA would otherwise
+    simplify around it into another program, whose answers drift from a
+    host-built full layout's in the last bits."""
+    idx = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (k, n))
+    return jax.lax.optimization_barrier((idx, jnp.ones((k, n), dtype=bool)))
+
+
+def _gather(x, idx, mask):
+    """Dense (N, K) -> padded (K, Bmax) buckets (padding zeroed)."""
+    return jnp.where(mask, jnp.take_along_axis(x.T, idx, axis=1), 0.0)
+
+
+def _scatter(xb, idx, mask, n):
+    """Padded (K, Bmax) buckets -> dense (N, K). A scatter-ADD, not a set:
+    a row's real ids are distinct, but batch-padded buckets replicate id 0
+    in the padding, and a colliding ``.set`` picks an unspecified writer —
+    masked padding adds an exact 0.0 instead."""
+    k = idx.shape[0]
+    cols = jnp.broadcast_to(jnp.arange(k, dtype=idx.dtype)[:, None],
+                            idx.shape)
+    return jnp.zeros((n, k), xb.dtype).at[idx, cols].add(
+        jnp.where(mask, xb, 0.0))
+
+
+def _row_sums(xb, idx, mask, n):
+    """Per-user totals (N,) of a (K, Bmax) bucket tensor."""
+    return jnp.zeros(n, xb.dtype).at[idx.ravel()].add(
+        jnp.where(mask, xb, 0.0).ravel())
+
+
+def _bucket_fill(mode, fill, demands, capacities, weights, gamma, idx, mask):
+    """Gather each server's bucket of demands, weights and gamma (padding
+    carries gamma 0, so it always fills to zero) and return
+    ``fill_server(i, x_ext)``: server i's fill over its bucket."""
+    f = _server_fill(mode, fill)
+    gam_b = _gather(gamma, idx, mask)
+    dem_b = demands[idx]                                   # (K, Bmax, R)
+    phi_b = weights[idx]                                   # (K, Bmax)
+
+    def fill_server(i, x_ext):
+        return f(capacities[i], dem_b[i], phi_b[i], gam_b[i], x_ext)
+
+    return fill_server
+
+
+def _gauss_round(fill_server, idx, mask, sweep, n, xb, alpha):
+    """One damped Gauss-Seidel round over the servers ``sweep``, in order.
+    Row sums are re-derived from the buckets at the round start, then kept
+    up to date by O(Bmax) scatter-adds of each fill's delta (a bucket row
+    holds distinct user ids, so the adds never collide within a server).
+    Returns the new buckets and the round's max |delta|."""
+    xsum = _row_sums(xb, idx, mask, n)
+
+    def per_server(j, carry):
+        xb, xsum, resid = carry
+        i = sweep[j]
+        u = idx[i]
+        x_ext = xsum[u] - xb[i]
+        xi = jnp.where(mask[i], fill_server(i, x_ext), 0.0)
+        xi = (1.0 - alpha) * xb[i] + alpha * xi
+        delta = jnp.where(mask[i], xi - xb[i], 0.0)
+        return (xb.at[i].set(jnp.where(mask[i], xi, 0.0)),
+                xsum.at[u].add(delta),
+                jnp.maximum(resid, jnp.abs(delta).max()))
+
+    xb, _, resid = jax.lax.fori_loop(
+        0, sweep.shape[0], per_server,
+        (xb, xsum, jnp.asarray(0.0, xb.dtype)))
+    return xb, resid
+
+
+def _solve_core(demands, capacities, weights, gamma, x0, idx, mask, mode,
+                max_rounds, tol, servers=None, alpha0=1.0, scale=None,
+                fill="event", round_mode="gauss", accel="none"):
+    """Traced solver body shared by every jitted entry point.
 
     All array arguments are positional so ``jax.vmap`` maps over them
     directly; ``mode``/``max_rounds``/``tol`` close over the trace.
+
+    ``idx``/``mask`` are padded (K, Bmax) per-server user buckets: a
+    host-built ``layout.BucketedLayout``'s arrays for a sparse support, or
+    ``_full_layout`` (every server holds every user) for a dense problem.
+    The whole solve runs on gathered (K, Bmax[, R]) bucket arrays, so a
+    round costs O(nnz*R) — O(N*K*R) on the full layout. Padded slots carry
+    gamma 0, so fills return 0 for them and their deltas are exact zeros:
+    padding is inert in fills, row sums and the residual (the same trick as
+    ``batch_problems``).
+
     ``scale`` overrides the residual-acceptance scale (defaults to
     ``gamma.max()`` — right for PS-DSF where gamma is the per-server
     monopolization; baseline fills pass the per-server gamma scale
@@ -382,18 +488,16 @@ def _solve_core(demands, capacities, weights, gamma, x0, mode, max_rounds,
     its fixed point. Callers restricting the sweep should verify with a full
     sweep afterwards (``psdsf_resolve_batched`` does).
 
-    ``fill`` selects the per-server fill engine: ``"event"`` (argsort +
-    saturation-event scan) or ``"bisect"`` (sort-free monotone bisection,
-    same fixed point — see ``_fill_one_server_rdm_bisect``).
+    ``fill`` selects the per-server fill engine (``_server_fill``).
 
-    ``round_mode`` selects the outer iteration: ``"gauss"`` (the historical
-    sequential Gauss-Seidel ``fori`` over servers) or ``"jacobi"`` — every
-    server fills against the PREVIOUS round's usage simultaneously, so one
-    round is a single vmapped fill over the server axis (the vectorization
-    the sequential ``fori`` blocks). Jacobi trades per-round progress for
-    parallel width and oscillates more than Gauss-Seidel on coupled
-    instances, so it starts pre-damped (alpha <= 0.5) and leans on the same
-    stall schedule; fixed points are identical where both converge.
+    ``round_mode`` selects the outer iteration: ``"gauss"`` (the sequential
+    Gauss-Seidel ``fori`` over servers, ``_gauss_round``) or ``"jacobi"`` —
+    every server fills against the PREVIOUS round's usage simultaneously,
+    so one round is a single vmapped fill over the server axis (the
+    vectorization the sequential ``fori`` blocks). Jacobi trades per-round
+    progress for parallel width and oscillates more than Gauss-Seidel on
+    coupled instances, so it starts pre-damped (alpha <= 0.5) and leans on
+    the same stall schedule; fixed points are identical where both converge.
 
     The rebuild map has small limit cycles on large instances (the paper
     leaves sweep convergence open, footnote 5); residuals stall ~0.1% of
@@ -401,151 +505,36 @@ def _solve_core(demands, capacities, weights, gamma, x0, mode, max_rounds,
     the cycle amplitude proportionally to ``a``, so the schedule lets ``a``
     fall to 0.01 (a 100x residual reduction) once the residual stops
     contracting; exact small instances converge before any damping starts.
+    A flat residual is not always a cycle: where the rebuild map is a
+    translation, or contracts slowly along one direction, damping only
+    slows the approach, so such a step lifts the damping (see ``body``).
 
     ``accel="anderson"`` wraps the damped sweep in safeguarded Anderson
-    mixing (``_anderson_rounds``) and returns the extended tuple
-    (x, rounds, residual, accel_hits, accel_rejects); the default
-    ``"none"`` keeps the historical while_loop (and 3-tuple) byte-for-byte.
-    """
-    scale = jnp.maximum(1.0, gamma.max() if scale is None else scale)
-    k = gamma.shape[1]
-    sweep = jnp.arange(k, dtype=jnp.int32) if servers is None else servers
-    if mode not in ("rdm", "tdm"):
-        raise ValueError(f"mode must be 'rdm' or 'tdm': {mode!r}")
-    if fill not in ("event", "bisect"):
-        raise ValueError(f"fill must be 'event' or 'bisect': {fill!r}")
-    if round_mode not in ("gauss", "jacobi"):
-        raise ValueError(
-            f"round must be 'gauss' or 'jacobi': {round_mode!r}")
-    _check_accel(accel)
+    mixing (``_anderson_rounds``); the mixing state is the packed
+    (K, Bmax) bucket tensor, so history memory scales with nnz, not N*K.
 
-    def fill_server(i, x_ext):
-        if mode == "rdm":
-            f = (_fill_one_server_rdm_bisect if fill == "bisect"
-                 else _fill_one_server_rdm)
-            return f(capacities[i], demands, weights, gamma[:, i], x_ext)
-        f = (_fill_one_server_tdm_bisect if fill == "bisect"
-             else _fill_one_server_tdm)
-        return f(demands, weights, gamma[:, i], x_ext)
-
-    if round_mode == "jacobi":
-        # damped Jacobi: every listed server refills against the previous
-        # round's usage in one vmapped shot
-        alpha0 = min(alpha0, 0.5)
-        fill_all = jax.vmap(fill_server, in_axes=(0, 1), out_axes=1)
-
-        def one_round(x, alpha):
-            x_ext = x.sum(axis=1, keepdims=True) - x            # (N, K)
-            xi = fill_all(sweep, x_ext[:, sweep])
-            return x.at[:, sweep].set(
-                (1.0 - alpha) * x[:, sweep] + alpha * xi)
-    else:
-        def one_round(x, alpha):
-            def per_server(j, x):
-                i = sweep[j]
-                x_ext = x.sum(axis=1) - x[:, i]
-                xi = fill_server(i, x_ext)
-                return x.at[:, i].set((1.0 - alpha) * x[:, i] + alpha * xi)
-            return jax.lax.fori_loop(0, sweep.shape[0], per_server, x)
-
-    if accel == "anderson":
-        def acc_round(x, alpha):
-            x_new = one_round(x, alpha)
-            return x_new, jnp.abs(x_new - x).max()
-
-        return _anderson_rounds(acc_round, x0, max_rounds, tol, scale,
-                                alpha0)
-
-    def cond(carry):
-        _, rounds, _, _, resid = carry
-        return (rounds < max_rounds) & (resid > tol * scale)
-
-    def body(carry):
-        x, rounds, prev_norm, alpha, _ = carry
-        x_new = one_round(x, alpha)
-        resid = jnp.abs(x_new - x).max()
-        # Stall detection on the ALPHA-NORMALIZED residual: on a limit cycle
-        # resid ~ alpha * amplitude, so resid/alpha stays flat (shrink every
-        # round of the descent), while true contraction shrinks it (never
-        # damp a converging sweep).
-        norm = resid / alpha
-        stall = (rounds >= 3) & (norm > 0.9 * prev_norm) & (alpha > 0.01)
-        alpha = jnp.where(stall, alpha * 0.7, alpha)
-        return x_new, rounds + 1, norm, alpha, resid
-
-    big = jnp.array(jnp.inf, dtype=x0.dtype)
-    x, rounds, _, _, resid = jax.lax.while_loop(
-        cond, body, (x0, jnp.array(0), big, jnp.array(alpha0, x0.dtype), big))
-    return x, rounds, resid
-
-
-def _solve_core_bucketed(demands, capacities, weights, gamma, x0, idx, mask,
-                         mode, max_rounds, tol, servers=None, alpha0=1.0,
-                         scale=None, fill="event", round_mode="gauss",
-                         accel="none"):
-    """Bucketed twin of ``_solve_core`` for sparse eligibility.
-
-    ``idx``/``mask`` are a ``layout.BucketedLayout``'s padded (K, Bmax)
-    per-server user buckets (built host-side — the bucket build argsorts a
-    data-dependent support, so it cannot live in the trace). The whole
-    solve runs on gathered (K, Bmax[, R]) bucket arrays: each server's fill
-    sees only its bucket's rows, and the per-user row sums feeding the
-    external floors are maintained by O(Bmax) scatter-adds of each fill's
-    delta (each bucket row holds distinct user ids, so the adds never
-    collide within a server). The dense core's per-server
-    ``x.sum(axis=1)`` is O(N*K) *per server*; here a round costs O(nnz*R)
-    — the asymptotic win the ``sparse_scale`` benchmark gates.
-
-    Padding discipline (same trick as ``batch_problems``): padded slots
-    carry gamma 0, so fills return 0 for them and their deltas are exact
-    zeros — padding is inert in fills, row sums, and the residual. Row
-    sums are re-derived from the buckets at every round start, mirroring
-    the dense sweep's one-reduction-per-round robustness.
-
-    ``servers``/``alpha0``/``scale``/``fill``/``round_mode``/``accel`` as
-    in ``_solve_core``; fixed points are identical (parity-gated at 1e-9 by
-    tests/test_layout.py). Returns (x dense (N, K), rounds, residual), plus
-    (accel_hits, accel_rejects) under ``accel="anderson"`` — the mixing
-    state is the packed (K, Bmax) bucket tensor, so history memory scales
-    with nnz, not N*K.
+    Returns (x dense (N, K), rounds, residual), plus (accel_hits,
+    accel_rejects) under ``accel="anderson"``.
     """
     scale = jnp.maximum(1.0, gamma.max() if scale is None else scale)
     n, k = gamma.shape
     dt = x0.dtype
     sweep = jnp.arange(k, dtype=jnp.int32) if servers is None else servers
-    if mode not in ("rdm", "tdm"):
-        raise ValueError(f"mode must be 'rdm' or 'tdm': {mode!r}")
-    if fill not in ("event", "bisect"):
-        raise ValueError(f"fill must be 'event' or 'bisect': {fill!r}")
     if round_mode not in ("gauss", "jacobi"):
         raise ValueError(
             f"round must be 'gauss' or 'jacobi': {round_mode!r}")
     _check_accel(accel)
 
-    gam_b = jnp.where(mask, jnp.take_along_axis(gamma.T, idx, axis=1), 0.0)
-    dem_b = demands[idx]                                   # (K, Bmax, R)
-    phi_b = weights[idx]                                   # (K, Bmax)
-    xb0 = jnp.where(mask, jnp.take_along_axis(x0.T, idx, axis=1), 0.0)
-
-    def fill_server(i, x_ext):
-        if mode == "rdm":
-            f = (_fill_one_server_rdm_bisect if fill == "bisect"
-                 else _fill_one_server_rdm)
-            return f(capacities[i], dem_b[i], phi_b[i], gam_b[i], x_ext)
-        f = (_fill_one_server_tdm_bisect if fill == "bisect"
-             else _fill_one_server_tdm)
-        return f(dem_b[i], phi_b[i], gam_b[i], x_ext)
-
-    def row_sums(xb):
-        return jnp.zeros(n, dt).at[idx.ravel()].add(
-            jnp.where(mask, xb, 0.0).ravel())
+    fill_server = _bucket_fill(mode, fill, demands, capacities, weights,
+                               gamma, idx, mask)
+    xb0 = _gather(x0, idx, mask)
 
     if round_mode == "jacobi":
         alpha0 = min(alpha0, 0.5)
         fill_all = jax.vmap(fill_server, in_axes=(0, 0))
 
         def one_round(xb, alpha):
-            xsum = row_sums(xb)
+            xsum = _row_sums(xb, idx, mask, n)
             x_ext = xsum[idx[sweep]] - xb[sweep]
             xi = jnp.where(mask[sweep], fill_all(sweep, x_ext), 0.0)
             new = (1.0 - alpha) * xb[sweep] + alpha * xi
@@ -553,24 +542,7 @@ def _solve_core_bucketed(demands, capacities, weights, gamma, x0, idx, mask,
             return xb.at[sweep].set(new), resid
     else:
         def one_round(xb, alpha):
-            xsum = row_sums(xb)
-
-            def per_server(j, carry):
-                xb, xsum, resid = carry
-                i = sweep[j]
-                u = idx[i]
-                x_ext = xsum[u] - xb[i]
-                xi = jnp.where(mask[i], fill_server(i, x_ext), 0.0)
-                xi = (1.0 - alpha) * xb[i] + alpha * xi
-                delta = jnp.where(mask[i], xi - xb[i], 0.0)
-                return (xb.at[i].set(jnp.where(mask[i], xi, 0.0)),
-                        xsum.at[u].add(delta),
-                        jnp.maximum(resid, jnp.abs(delta).max()))
-
-            xb, _, resid = jax.lax.fori_loop(
-                0, sweep.shape[0], per_server,
-                (xb, xsum, jnp.asarray(0.0, dt)))
-            return xb, resid
+            return _gauss_round(fill_server, idx, mask, sweep, n, xb, alpha)
 
     if accel == "anderson":
         xb, rounds, resid, hits, rejects = _anderson_rounds(
@@ -578,29 +550,47 @@ def _solve_core_bucketed(demands, capacities, weights, gamma, x0, idx, mask,
         stats = (hits, rejects)
     else:
         def cond(carry):
-            _, rounds, _, _, resid = carry
+            _, rounds, _, _, resid, _, _ = carry
             return (rounds < max_rounds) & (resid > tol * scale)
 
         def body(carry):
-            xb, rounds, prev_norm, alpha, _ = carry
+            xb, rounds, prev_norm, alpha, _, prev_step, calm = carry
             xb_new, resid = one_round(xb, alpha)
-            # same alpha-normalized stall schedule as the dense core
+            # Stall detection on the ALPHA-NORMALIZED residual: on a limit
+            # cycle resid ~ alpha * amplitude, so resid/alpha stays flat
+            # (shrink every round of the descent), while true contraction
+            # shrinks it (never damp a converging sweep). A flat residual
+            # is not always a cycle. Along a step that keeps its direction
+            # the undamped multiplier is mu = 1 - (1 - ratio) / alpha, with
+            # ratio = norm / prev_norm. A mode that damping holds (mu <= -1)
+            # contracts by 1 - 2 alpha or faster, so a ratio above
+            # max(0.9, 1 - alpha/2) (mu > 1/2) is a monotone approach that
+            # damping only slows. Such a step lifts the damping, and the
+            # detector waits 3 rounds as at the start, when it repeats the
+            # last one (a translation: turn <= 1e-3 alpha norm) or, at the
+            # damping floor, turns no more than a smooth damped flow does
+            # (turn <= alpha norm; a sweep chattering across a fill's
+            # switch turns by O(norm)).
             norm = resid / alpha
-            stall = (rounds >= 3) & (norm > 0.9 * prev_norm) & (alpha > 0.01)
-            alpha = jnp.where(stall, alpha * 0.7, alpha)
-            return xb_new, rounds + 1, norm, alpha, resid
+            step = (xb_new - xb) / alpha
+            turn = jnp.abs(step - prev_step * (norm / prev_norm)).max()
+            slow = norm > jnp.maximum(0.9, 1.0 - 0.5 * alpha) * prev_norm
+            at_floor = (alpha <= 0.01) & (rounds >= calm + 3)
+            lift = slow & ((turn <= 1e-3 * alpha * norm)
+                           | (at_floor & (turn <= alpha * norm)))
+            stall = ((rounds >= calm + 3) & (norm > 0.9 * prev_norm)
+                     & (alpha > 0.01))
+            alpha = jnp.where(lift, alpha0,
+                              jnp.where(stall, alpha * 0.7, alpha))
+            calm = jnp.where(lift, rounds + 1, calm)
+            return xb_new, rounds + 1, norm, alpha, resid, step, calm
 
         big = jnp.array(jnp.inf, dtype=dt)
-        xb, rounds, _, _, resid = jax.lax.while_loop(
-            cond, body, (xb0, jnp.array(0), big, jnp.array(alpha0, dt), big))
+        xb, rounds, _, _, resid, _, _ = jax.lax.while_loop(
+            cond, body, (xb0, jnp.array(0), big, jnp.array(alpha0, dt), big,
+                         jnp.zeros_like(xb0), jnp.array(0)))
         stats = ()
-    cols = jnp.broadcast_to(jnp.arange(k, dtype=idx.dtype)[:, None],
-                            idx.shape)
-    # scatter-ADD, not set: a row's real ids are distinct, but batch-padded
-    # buckets replicate id 0 in the padding, and a colliding .set picks an
-    # unspecified writer — masked padding adds an exact 0.0 instead
-    x = jnp.zeros((n, k), dt).at[idx, cols].add(jnp.where(mask, xb, 0.0))
-    return (x, rounds, resid) + stats
+    return (_scatter(xb, idx, mask, n), rounds, resid) + stats
 
 
 def _solve_dtype(demands):
@@ -680,17 +670,19 @@ def _repack_refill_core(demands, capacities, weights, gamma, x, rounds,
     jnp mirror of ``placement.repack_refill``). Acceptance matches the
     numpy contract — tight OR loose convergence counts (``SolveInfo``'s
     ``converged`` includes ``approx``), so limit-cycling instances accept
-    the same refills on both backends. Returns the accepted
-    (x, rounds, resid)."""
+    the same refills on both backends. The refills sweep the full layout
+    (the repack moves tasks anywhere a user is eligible). Returns the
+    accepted (x, rounds, resid)."""
     scale = jnp.maximum(1.0, gamma.max())
     s0 = stranded_fraction_jnp(demands, capacities, gamma, x)
+    idx, mask = _full_layout(*gamma.shape)
 
     def body(_, carry):
         x_b, s_b, rounds_b, resid_b = carry
         xr = _repack_core(x_b, demands, capacities, weights, gamma, mode)
         x2, r2, res2 = _solve_core(demands, capacities, weights, gamma, xr,
-                                   mode, max_rounds, tol, fill=fill,
-                                   round_mode=round_mode)
+                                   idx, mask, mode, max_rounds, tol,
+                                   fill=fill, round_mode=round_mode)
         s2 = stranded_fraction_jnp(demands, capacities, gamma, x2)
         accept_tol = jnp.maximum(tol, loose_tol)
         ok = (res2 <= accept_tol * scale) & (s2 < s_b - min_gain)
@@ -713,30 +705,14 @@ def _check_placement(placement: str) -> None:
                          f"(numpy engine only)")
 
 
-def _check_buckets(layout: str, buckets) -> None:
-    """Trace-time gate for the bucketed layout args: ``layout`` is a static
-    name, ``buckets`` the (idx, mask) arrays of a host-built
-    ``layout.BucketedLayout`` (``"auto"`` has no meaning here — density
-    inspection is host-side; ``engine.solve`` and the schedulers resolve it
-    before calling in)."""
-    if layout not in ("dense", "bucketed"):
-        raise ValueError(
-            f"jitted entry points take layout='dense'|'bucketed' (resolve "
-            f"'auto' host-side, e.g. via layout.resolve_layout): {layout!r}")
-    if layout == "bucketed" and buckets is None:
-        raise ValueError("layout='bucketed' needs buckets=(idx, mask) from "
-                         "a BucketedLayout (host-built)")
-
-
 @functools.partial(jax.jit,
                    static_argnames=("mode", "max_rounds", "placement",
-                                    "fill", "round", "layout", "accel"))
+                                    "fill", "round", "accel"))
 def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
                     mode: str = "rdm", max_rounds: int = 256,
                     tol: float = 1e-6, placement: str = "level",
                     fill: str = "event", round: str = "gauss",
-                    layout: str = "dense", buckets=None,
-                    accel: str = "none"):
+                    buckets=None, accel: str = "none"):
     """Solve PS-DSF. Returns (x (N,K), rounds, residual) — plus
     (accel_hits, accel_rejects) when ``accel="anderson"``.
 
@@ -764,10 +740,11 @@ def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
     per-server lexicographic optimum — see ``flowrouter``); ``"bestfit"``
     is numpy-only and rejected here.
 
-    ``layout="bucketed"`` with ``buckets=(idx, mask)`` (a host-built
-    ``layout.BucketedLayout``'s padded arrays) runs the O(nnz) bucketed
-    sweep ``_solve_core_bucketed`` — same fixed point, gated >= 3x on the
-    pinned sparse instance. The headroom repack stays dense either way.
+    ``buckets=(idx, mask)`` (a host-built ``layout.BucketedLayout``'s
+    padded arrays) sweeps each server's eligibility bucket only — O(nnz)
+    a round, same fixed point; ``None`` is the full layout (every server
+    holds every user). The headroom repack sweeps the full layout either
+    way.
 
     ``accel="anderson"`` runs the safeguarded Anderson-mixed outer
     iteration (``_anderson_rounds``) and extends the return tuple with
@@ -776,22 +753,14 @@ def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
     nothing to extrapolate.
     """
     _check_placement(placement)
-    _check_buckets(layout, buckets)
-    _check_accel(accel)
     n, k = gamma.shape
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((n, k), dtype=dtype)
-    if layout == "bucketed":
-        idx, mask = buckets
-        out = _solve_core_bucketed(demands, capacities, weights, gamma,
-                                   x0.astype(dtype), idx, mask, mode,
-                                   max_rounds, tol, fill=fill,
-                                   round_mode=round, accel=accel)
-    else:
-        out = _solve_core(demands, capacities, weights, gamma,
-                          x0.astype(dtype), mode, max_rounds, tol, fill=fill,
-                          round_mode=round, accel=accel)
+    idx, mask = _full_layout(n, k) if buckets is None else buckets
+    out = _solve_core(demands, capacities, weights, gamma, x0.astype(dtype),
+                      idx, mask, mode, max_rounds, tol, fill=fill,
+                      round_mode=round, accel=accel)
     if placement == "headroom":
         fixed = _repack_refill_core(demands, capacities, weights, gamma,
                                     *out[:3], mode, max_rounds, tol,
@@ -800,15 +769,25 @@ def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
     return out
 
 
+def _batch_buckets(buckets, b, n, k):
+    """Per-problem (B, K, Bmax) bucket stacks for the batched entries.
+    ``None`` is the full layout, broadcast over the batch: shared through
+    ``vmap(in_axes=None)`` it compiles to another program, whose answers
+    drift from a host-built full layout's in the last bits."""
+    if buckets is not None:
+        return buckets
+    return tuple(jnp.broadcast_to(a, (b,) + a.shape)
+                 for a in _full_layout(n, k))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("mode", "max_rounds", "placement",
-                                    "fill", "round", "layout", "accel"))
+                                    "fill", "round", "accel"))
 def psdsf_solve_batched(demands, capacities, weights, gamma, *, x0=None,
                         mode: str = "rdm", max_rounds: int = 256,
                         tol: float = 1e-6, placement: str = "level",
                         fill: str = "event", round: str = "gauss",
-                        layout: str = "dense", buckets=None,
-                        accel: str = "none"):
+                        buckets=None, accel: str = "none"):
     """Solve B independent PS-DSF problems in one jitted call.
 
     Shapes: demands (B, N, R), capacities (B, K, R), weights (B, N),
@@ -820,38 +799,20 @@ def psdsf_solve_batched(demands, capacities, weights, gamma, *, x0=None,
     (see module docstring). ``placement``/``fill``/``round``/``accel`` as
     in ``psdsf_solve_jax`` (``accel="anderson"`` appends per-problem
     (accel_hits, accel_rejects) vectors to the return tuple).
-    ``layout="bucketed"`` takes per-problem buckets
-    — (B, K, Bmax) idx/mask stacks (pad each problem's layout to a common
-    Bmax with masked slots; padding is inert like the user/server padding).
+    ``buckets`` are per-problem (B, K, Bmax) idx/mask stacks (pad each
+    problem's layout to a common Bmax with masked slots; padding is inert
+    like the user/server padding); ``None`` is the full layout.
     """
     _check_placement(placement)
-    _check_buckets(layout, buckets)
-    _check_accel(accel)
     b, n, k = gamma.shape
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((b, n, k), dtype=dtype)
+    idx, mask = _batch_buckets(buckets, b, n, k)
 
-    if layout == "bucketed":
-        idx, mask = buckets
-
-        def solve_b(d, c, w, g, x0_, idx_, mask_):
-            out = _solve_core_bucketed(d, c, w, g, x0_, idx_, mask_, mode,
-                                       max_rounds, tol, fill=fill,
-                                       round_mode=round, accel=accel)
-            if placement == "headroom":
-                fixed = _repack_refill_core(d, c, w, g, *out[:3], mode,
-                                            max_rounds, tol, fill=fill,
-                                            round_mode=round)
-                out = fixed + out[3:]
-            return out
-
-        return jax.vmap(solve_b)(demands, capacities, weights, gamma,
-                                 x0.astype(dtype), idx, mask)
-
-    def solve(d, c, w, g, x0_):
-        out = _solve_core(d, c, w, g, x0_, mode, max_rounds, tol, fill=fill,
-                          round_mode=round, accel=accel)
+    def solve(d, c, w, g, x0_, idx_, mask_):
+        out = _solve_core(d, c, w, g, x0_, idx_, mask_, mode, max_rounds,
+                          tol, fill=fill, round_mode=round, accel=accel)
         if placement == "headroom":
             fixed = _repack_refill_core(d, c, w, g, *out[:3], mode,
                                         max_rounds, tol, fill=fill,
@@ -860,18 +821,17 @@ def psdsf_solve_batched(demands, capacities, weights, gamma, *, x0=None,
         return out
 
     return jax.vmap(solve)(demands, capacities, weights, gamma,
-                           x0.astype(dtype))
+                           x0.astype(dtype), idx, mask)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mode", "max_rounds", "placement",
-                                    "fill", "round", "layout", "accel"))
+                                    "fill", "round", "accel"))
 def psdsf_resolve_batched(demands, capacities, weights, gamma, x0, servers, *,
                           mode: str = "rdm", max_rounds: int = 64,
                           tol: float = 1e-4, placement: str = "level",
                           fill: str = "event", round: str = "gauss",
-                          layout: str = "dense", buckets=None,
-                          accel: str = "none"):
+                          buckets=None, accel: str = "none"):
     """Event-driven incremental re-solve of B perturbed problems.
 
     ``servers`` (B, S) int32 lists the servers each scenario's events touch
@@ -889,29 +849,23 @@ def psdsf_resolve_batched(demands, capacities, weights, gamma, x0, servers, *,
     ``placement="headroom"`` appends repack-and-refill passes after the
     verification sweep (full sweeps — the repack is global by nature).
     ``fill``/``round`` select the fill engine and outer iteration for both
-    phases, as in ``psdsf_solve_jax``; ``layout="bucketed"`` (with
-    (B, K, Bmax) ``buckets``) runs BOTH the restricted and the
-    verification phase on the bucketed core — the restricted+verify
-    exactness contract is layout-independent. ``accel="anderson"`` runs the
+    phases, as in ``psdsf_solve_jax``; ``buckets`` ((B, K, Bmax) stacks, or
+    ``None`` for the full layout) serve BOTH the restricted and the
+    verification phase — the restricted+verify exactness contract is
+    layout-independent. ``accel="anderson"`` runs the
     safeguarded Anderson mixer in BOTH phases and appends summed
     (accel_hits, accel_rejects) to the return tuple — this is where the
     axis pays off most: a warm re-solve near a limit cycle finally
     contracts instead of re-orbiting.
     """
     _check_placement(placement)
-    _check_buckets(layout, buckets)
-    _check_accel(accel)
 
-    def one(d, c, w, g, x0_, srv, *bkt):
+    def one(d, c, w, g, x0_, srv, idx_, mask_):
         def core(x_init, servers=None, alpha0=1.0):
-            if layout == "bucketed":
-                return _solve_core_bucketed(
-                    d, c, w, g, x_init, bkt[0], bkt[1], mode, max_rounds,
-                    tol, servers=servers, alpha0=alpha0, fill=fill,
-                    round_mode=round, accel=accel)
-            return _solve_core(d, c, w, g, x_init, mode, max_rounds, tol,
-                               servers=servers, alpha0=alpha0, fill=fill,
-                               round_mode=round, accel=accel)
+            return _solve_core(d, c, w, g, x_init, idx_, mask_, mode,
+                               max_rounds, tol, servers=servers,
+                               alpha0=alpha0, fill=fill, round_mode=round,
+                               accel=accel)
 
         # The warm start is near the fixed point; alpha0 = 0.3 is enough to
         # absorb a cell-local perturbation in a few sweeps without fully
@@ -934,12 +888,9 @@ def psdsf_resolve_batched(demands, capacities, weights, gamma, x0, servers, *,
                     out1[3] + out2[3], out1[4] + out2[4])
         return x, r_restricted, r_full, resid
 
-    x0c = x0.astype(_solve_dtype(demands))
-    if layout == "bucketed":
-        idx, mask = buckets
-        return jax.vmap(one)(demands, capacities, weights, gamma, x0c,
-                             servers, idx, mask)
-    return jax.vmap(one)(demands, capacities, weights, gamma, x0c, servers)
+    idx, mask = _batch_buckets(buckets, *gamma.shape)
+    return jax.vmap(one)(demands, capacities, weights, gamma,
+                         x0.astype(_solve_dtype(demands)), servers, idx, mask)
 
 
 def batch_problems(problems, dtype=np.float32):

@@ -143,7 +143,8 @@ class ChurnSimulator:
     ``layout`` ("dense"/"bucketed"/"auto") picks the sweep's data layout
     (``core.layout``): bucketed sweeps each server's eligibility bucket —
     O(nnz) per round — with buckets built once from the ACTIVE support at
-    construction. Departures mask bucket slots in place (no rebuild);
+    construction; dense passes no buckets, and the one jitted sweep core
+    runs the full layout (every server holds every user). Departures mask bucket slots in place (no rebuild);
     an arrival the layout never saw rebuilds it loudly (recompile + the
     per-record ``layout_rebuilds`` flag). "auto" resolves by density of
     the initial active support.
@@ -306,7 +307,7 @@ class ChurnSimulator:
                 self._demands, self._caps, self._weights, self._elig, *up, x0,
                 mechanism=self.mechanism, max_rounds=self.max_rounds,
                 tol=self.tol, placement=self.placement, fill=self.fill,
-                round=self.round, layout=self.layout,
+                round=self.round,
                 buckets=(None if self._blayout is None
                          else (self._idx_j, self._mask_j)),
                 accel=self.accel))
@@ -520,19 +521,18 @@ def _resolve_fn():
     import jax.numpy as jnp
     import jax
 
-    from repro.core.baselines_jax import (_routed_fill_core,
-                                          level_rate_matrix_jnp)
-    from repro.core.psdsf_jax import (_check_accel, _repack_refill_core,
-                                      _solve_core, _solve_core_bucketed,
+    from repro.core.baselines_jax import _routed_fill, level_rate_matrix_jnp
+    from repro.core.psdsf_jax import (_check_accel, _full_layout,
+                                      _repack_refill_core, _solve_core,
                                       gamma_matrix_jnp)
 
     @functools.partial(jax.jit, static_argnames=("mechanism", "max_rounds",
                                                  "placement", "fill",
-                                                 "round", "layout", "accel"))
+                                                 "round", "accel"))
     def resolve(demands, capacities, weights, eligibility, active, departed,
                 cap_scale, x0, *, mechanism, max_rounds, tol,
                 placement="level", fill="event", round="gauss",
-                layout="dense", buckets=None, accel="none"):
+                buckets=None, accel="none"):
         _check_accel(accel)
         caps_eff = capacities * cap_scale[:, None]
         g = gamma_matrix_jnp(demands, caps_eff, eligibility)
@@ -559,31 +559,22 @@ def _resolve_fn():
         if placement == "headroom" and not psdsf:
             # global-share mechanisms route via the one-shot exact fill;
             # there is no fixed point to warm-start
-            if layout == "bucketed":
+            if buckets is not None:
                 raise ValueError("routed headroom fill has no bucketed "
                                  "form; guarded in ChurnSimulator.__init__")
-            out = _routed_fill_core(demands, caps_eff, weights, lg)
-            if accel == "anderson":  # one-shot fill: nothing to accelerate
-                zero = jnp.asarray(0, jnp.int32)
-                out = out + (zero, zero)
+            out = _routed_fill(demands, caps_eff, weights, lg, accel)
             return out + (out[0].sum(), scale)
         if x0 is None:
             x0 = jnp.zeros(lg.shape, dtype=demands.dtype)
         x0 = jnp.where((active & ~departed)[:, None], x0, 0.0)
-        if layout == "bucketed":
-            # departure-only churn masks bucket slots in place: the layout
-            # was built from the active support, so departed users' slots
-            # exist and simply go dark under the activity mask
-            idx, mask = buckets
-            out = _solve_core_bucketed(demands, caps_eff, weights, lg, x0,
-                                       idx, mask & active[idx], mode,
-                                       max_rounds, tol, scale=scale,
-                                       fill=fill, round_mode=round,
-                                       accel=accel)
-        else:
-            out = _solve_core(demands, caps_eff, weights, lg, x0, mode,
-                              max_rounds, tol, scale=scale, fill=fill,
-                              round_mode=round, accel=accel)
+        # departure-only churn masks bucket slots in place: a bucketed
+        # layout was built from the active support, so departed users'
+        # slots exist and simply go dark under the activity mask
+        idx, mask = _full_layout(*lg.shape) if buckets is None else buckets
+        out = _solve_core(demands, caps_eff, weights, lg, x0, idx,
+                          mask & active[idx], mode, max_rounds, tol,
+                          scale=scale, fill=fill, round_mode=round,
+                          accel=accel)
         if placement == "headroom":
             fixed = _repack_refill_core(demands, caps_eff, weights, g,
                                         *out[:3], mode, max_rounds, tol,

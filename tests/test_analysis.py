@@ -443,13 +443,13 @@ class TestRepoGate:
         scratch = tmp_path / "repo"
         for rel in ("src", "tests", "benchmarks"):
             shutil.copytree(REPO_ROOT / rel, scratch / rel)
-        # drop the mode validation from both jitted solve cores
+        # drop the mode validation from the jitted sweep's fill dispatch
         core = scratch / "src/repro/core/psdsf_jax.py"
         text = core.read_text()
         guard = ('    if mode not in ("rdm", "tdm"):\n'
                  '        raise ValueError('
                  'f"mode must be \'rdm\' or \'tdm\': {mode!r}")\n')
-        assert text.count(guard) == 2
+        assert text.count(guard) == 1
         core.write_text(text.replace(guard, ""))
         # delete one kernel package's reference implementation
         (scratch / "src/repro/kernels/psdsf_vds/ref.py").unlink()

@@ -75,8 +75,8 @@ class _HostRoundTrip:
             jnp.zeros(self.prob.num_users, bool),
             jnp.asarray(self.cap_scale, jnp.float32),
             None if x0 is None else jnp.asarray(x0, jnp.float32),
-            max_rounds=MAX_ROUNDS, tol=1e-6, layout=self.layout,
-            buckets=self.buckets, **self.kw)
+            max_rounds=MAX_ROUNDS, tol=1e-6, buckets=self.buckets,
+            **self.kw)
 
     def step(self, events):
         rebuild = False

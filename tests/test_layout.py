@@ -36,6 +36,14 @@ def x64():
         yield
 
 
+def _host_full_layout(n, k):
+    """The full layout built on the host, as a sparse one is: the arrays
+    ``buckets=None`` must match bit for bit."""
+    import jax.numpy as jnp
+    lay = BucketedLayout.from_support(np.ones((n, k)))
+    return jnp.asarray(lay.indices), jnp.asarray(lay.mask)
+
+
 def _degenerate_problem():
     """Dense random instance with an empty server and an unplaceable user."""
     prob = dense_random_instance(num_users=32, num_servers=8)
@@ -272,13 +280,30 @@ class TestActiveSetSweep:
 
 
 class TestJaxParity:
-    def test_engine_jax_psdsf_parity(self, x64):
+    @pytest.mark.parametrize("support", ["eligible", "full"])
+    def test_engine_jax_psdsf_parity(self, x64, support):
+        import jax.numpy as jnp
+
+        from repro.core.gamma import gamma_matrix
+        from repro.core.psdsf_jax import psdsf_solve_jax
         prob, _ = sparse_cell_instance(num_users=600, num_servers=64,
                                        density=0.05, cells=8, seed=6)
         for mech in ("psdsf-rdm", "psdsf-tdm"):
             a_d, i_d = engine.solve(prob, mech, backend="jax",
                                     layout="dense", fill="bisect",
                                     max_rounds=40)
+            if support == "full":
+                # the dense solve passed buckets=None: the full layout
+                x, rounds, _ = psdsf_solve_jax(
+                    *(jnp.asarray(a) for a in (
+                        prob.demands, prob.capacities, prob.weights,
+                        gamma_matrix(prob))),
+                    mode=mech.removeprefix("psdsf-"), fill="bisect",
+                    max_rounds=40, buckets=_host_full_layout(
+                        prob.num_users, prob.num_servers))
+                np.testing.assert_array_equal(np.asarray(x), a_d.x)
+                assert int(rounds) == i_d.rounds
+                continue
             a_b, i_b = engine.solve(prob, mech, backend="jax",
                                     layout="bucketed", fill="bisect",
                                     max_rounds=40)
@@ -303,7 +328,8 @@ class TestJaxParity:
             assert i_b.layout == "bucketed"
             np.testing.assert_allclose(a_b.x, a_d.x, atol=PARITY_ATOL)
 
-    def test_batched_parity(self, x64):
+    @pytest.mark.parametrize("support", ["eligible", "full"])
+    def test_batched_parity(self, x64, support):
         import jax.numpy as jnp
 
         from repro.core.psdsf_jax import batch_problems, psdsf_solve_batched
@@ -313,7 +339,9 @@ class TestJaxParity:
         bat = batch_problems(probs, dtype=np.float64)
         d, c, w, g = (bat["demands"], bat["capacities"], bat["weights"],
                       bat["gamma"])
-        lays = [BucketedLayout.from_support(np.asarray(g[j]) > 0)
+        full = support == "full"
+        lays = [BucketedLayout.from_support(
+                    np.ones(g.shape[1:]) if full else np.asarray(g[j]) > 0)
                 for j in range(2)]
         bmax = max(lay.bucket_max for lay in lays)
         idx = np.stack([np.pad(lay.indices,
@@ -323,14 +351,18 @@ class TestJaxParity:
                                 ((0, 0), (0, bmax - lay.bucket_max)))
                          for lay in lays])
         xb, rb, _ = psdsf_solve_batched(
-            d, c, w, g, max_rounds=30, layout="bucketed",
+            d, c, w, g, max_rounds=30,
             buckets=(jnp.asarray(idx), jnp.asarray(mask)))
         xd, rd, _ = psdsf_solve_batched(d, c, w, g, max_rounds=30)
-        np.testing.assert_allclose(np.asarray(xb), np.asarray(xd),
-                                   atol=PARITY_ATOL)
+        if full:     # buckets=None IS the full layout, bit for bit
+            np.testing.assert_array_equal(np.asarray(xb), np.asarray(xd))
+        else:
+            np.testing.assert_allclose(np.asarray(xb), np.asarray(xd),
+                                       atol=PARITY_ATOL)
         np.testing.assert_array_equal(np.asarray(rb), np.asarray(rd))
 
-    def test_resolve_batched_parity(self, x64):
+    @pytest.mark.parametrize("support", ["eligible", "full"])
+    def test_resolve_batched_parity(self, x64, support):
         import jax.numpy as jnp
 
         from repro.core.psdsf_jax import batch_problems, psdsf_resolve_batched
@@ -343,7 +375,9 @@ class TestJaxParity:
         x0 = jnp.zeros_like(g)
         srv = jnp.asarray(
             np.tile(np.arange(8, dtype=np.int32), (2, 1)))
-        lays = [BucketedLayout.from_support(np.asarray(g[j]) > 0)
+        full = support == "full"
+        lays = [BucketedLayout.from_support(
+                    np.ones(g.shape[1:]) if full else np.asarray(g[j]) > 0)
                 for j in range(2)]
         bmax = max(lay.bucket_max for lay in lays)
         idx = np.stack([np.pad(lay.indices,
@@ -353,25 +387,36 @@ class TestJaxParity:
                                 ((0, 0), (0, bmax - lay.bucket_max)))
                          for lay in lays])
         xb, _, rb, resb = psdsf_resolve_batched(
-            d, c, w, g, x0, srv, max_rounds=30, layout="bucketed",
+            d, c, w, g, x0, srv, max_rounds=30,
             buckets=(jnp.asarray(idx), jnp.asarray(mask)))
         xd, _, rd, resd = psdsf_resolve_batched(d, c, w, g, x0, srv,
                                                 max_rounds=30)
-        np.testing.assert_allclose(np.asarray(xb), np.asarray(xd),
-                                   atol=PARITY_ATOL)
+        if full:     # buckets=None IS the full layout, bit for bit
+            np.testing.assert_array_equal(np.asarray(xb), np.asarray(xd))
+        else:
+            np.testing.assert_allclose(np.asarray(xb), np.asarray(xd),
+                                       atol=PARITY_ATOL)
         np.testing.assert_allclose(np.asarray(resb), np.asarray(resd),
                                    atol=1e-12)
 
 
 class TestDistributedParity:
-    @pytest.mark.parametrize("eng", ["numpy", "jax"])
+    @pytest.mark.parametrize("eng", ["numpy", "jax", "jax-full-layout"])
     def test_tick_parity_with_churn(self, eng):
         prob, _, _ = cell_cluster_instance(num_users=128, num_servers=32,
                                            cells=8, seed=2)
         from repro.core.dynamic import DistributedPSDSF
+        full = eng == "jax-full-layout"
+        eng = eng.removesuffix("-full-layout")
         d_d = DistributedPSDSF(prob, engine=eng, layout="dense")
-        d_b = DistributedPSDSF(prob, engine=eng, layout="bucketed")
-        assert d_b.layout == "bucketed" and d_b.bucket_max > 0
+        if full:
+            # the dense tick passes buckets=None: the full layout
+            d_b = DistributedPSDSF(prob, engine=eng, layout="dense")
+            d_b._buckets_j = _host_full_layout(prob.num_users,
+                                               prob.num_servers)
+        else:
+            d_b = DistributedPSDSF(prob, engine=eng, layout="bucketed")
+            assert d_b.layout == "bucketed" and d_b.bucket_max > 0
         for t in range(5):
             d_d.tick()
             d_b.tick()
@@ -380,7 +425,10 @@ class TestDistributedParity:
                 d_b.set_active(7, False)
         d_d.tick(servers=[1, 5, 9])
         d_b.tick(servers=[1, 5, 9])
-        np.testing.assert_allclose(d_b.x, d_d.x, atol=PARITY_ATOL)
+        if full:
+            np.testing.assert_array_equal(d_b.x, d_d.x)
+        else:
+            np.testing.assert_allclose(d_b.x, d_d.x, atol=PARITY_ATOL)
 
     def test_churn_simulator_bucketed_stream(self):
         # f32 jitted sweep: parity at f32 tolerance; the rebuild counter

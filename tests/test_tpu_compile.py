@@ -117,7 +117,7 @@ def test_churn_resolve_bucketed_fits_one_chip(one_chip):
         s((n, r)), s((k, r)), s((n,)), s((n, k)), s((n,), jnp.bool_),
         s((n,), jnp.bool_), s((k,)), s((n, k)), mechanism="psdsf-rdm",
         max_rounds=max_rounds, tol=s(()),
-        placement="level", fill="event", round="gauss", layout="bucketed",
+        placement="level", fill="event", round="gauss",
         buckets=(s((k, b), jnp.int32), s((k, b), jnp.bool_)),
         accel="none").compile()
     mem = compiled.memory_analysis()
