@@ -41,8 +41,10 @@ from repro.core import engine  # noqa: E402
 from repro.core.dynamic import min_vds_guarded  # noqa: E402
 from repro.core.gamma import gamma_matrix  # noqa: E402
 from repro.core.instances import sparse_cell_instance  # noqa: E402
+from repro.core.layout import BucketedLayout  # noqa: E402
 from repro.core.properties import check_feasible_rdm  # noqa: E402
 from repro.core.psdsf import SolveInfo  # noqa: E402
+from repro.core.types import AllocationProblem  # noqa: E402
 from repro.sched.churn import ChurnSimulator, poisson_churn_events  # noqa: E402
 
 #: phase 1 size: numpy's bucketed sweep solves it cold in about a minute
@@ -87,7 +89,9 @@ def phase_parity(num_users: int = PARITY_USERS,
     """Phase 1: the jitted engine against the numpy reference.
 
     (a) ``TRAJ_ROUNDS`` undamped rounds with ``tol=0`` on both engines
-    must agree on per-user totals within ``TRAJ_RTOL``; (b) cold solves at
+    must agree on per-user totals within ``TRAJ_RTOL`` (numpy runs on the
+    servers renumbered in the class-major order of the jitted round's
+    colouring, so both fill them in one order); (b) cold solves at
     the engine defaults must each pass ``ensure_converged`` (their totals
     gap is printed: on this limit-cycling instance the two damping
     schedules stop at different points of the cycle); (c) ``min_vds`` on
@@ -98,7 +102,11 @@ def phase_parity(num_users: int = PARITY_USERS,
     g = gamma_matrix(prob)
     scale = max(1.0, float(g.max()))
 
-    ref, _ = engine.solve(prob, "psdsf-rdm", tol=0.0,
+    colours = BucketedLayout.from_support(g > 0).colours
+    order = colours[colours < prob.num_servers]
+    in_order = AllocationProblem(prob.demands, prob.capacities[order],
+                                 prob.weights, prob.eligibility[:, order])
+    ref, _ = engine.solve(in_order, "psdsf-rdm", tol=0.0,
                           max_rounds=TRAJ_ROUNDS)
     got, _ = engine.solve(prob, "psdsf-rdm", backend="jax", tol=0.0,
                           max_rounds=TRAJ_ROUNDS)

@@ -21,7 +21,7 @@ import numpy as np
 from .baselines import LEVEL_FILL_MECHANISMS, level_rate_matrix
 from .placement import ROUTED_FILL_CORRECTORS, SolveInfo, stranded_fraction
 from .psdsf_jax import (_BIG, _batch_buckets, _check_accel, _check_placement,
-                        _full_layout, _solve_core, _solve_dtype,
+                        _layout_arrays, _solve_core, _solve_dtype,
                         gamma_matrix_jnp)
 from .types import Allocation, AllocationProblem
 
@@ -208,11 +208,12 @@ def baseline_solve_jax(demands, capacities, weights, level_gamma, *, x0=None,
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((n, k), dtype=dtype)
-    idx, mask = _full_layout(n, k) if buckets is None else buckets
+    idx, mask, colours = _layout_arrays(buckets, n, k)
     return _solve_core(demands, capacities, weights, level_gamma,
                        x0.astype(dtype), idx, mask, "rdm", max_rounds, tol,
                        scale=_gamma_scale(demands, capacities, level_gamma),
-                       fill=fill, round_mode=round, accel=accel)
+                       fill=fill, round_mode=round, accel=accel,
+                       colours=colours)
 
 
 @functools.partial(jax.jit, static_argnames=("max_rounds", "placement",
@@ -307,8 +308,8 @@ def solve_baseline_jax(problem: AllocationProblem, mechanism: str, x0=None,
         bucket_max = 0
         if resolved == "bucketed":
             blayout = BucketedLayout.from_support(lg > 0)
-            buckets = (jnp.asarray(blayout.indices),
-                       jnp.asarray(blayout.mask))
+            buckets = tuple(jnp.asarray(a) for a in (
+                blayout.indices, blayout.mask, blayout.colours))
             bucket_max = blayout.bucket_max
     if placement == "lexmm":
         from .flowrouter import lexmm_route

@@ -14,9 +14,11 @@ Two engines:
 * ``engine="numpy"`` — the reference oracle: a pure-Python loop over
   ``psdsf.server_fill_*`` per server. Exact (float64), easy to read, slow.
 * ``engine="jax"`` — the jitted sweep core's Gauss-Seidel round
-  (``psdsf_jax._gauss_round``) over the selected servers. Identical
-  Gauss-Seidel order and math, so the engines agree to fp32 round-off; this
-  is what makes 10^3-server ticks at scheduler rates feasible.
+  (``psdsf_jax._gauss_round``) over the selected servers, as a (len, 1)
+  sweep: one server a step, in the listed order, never the colour classes
+  the jitted solvers fill at once. Identical Gauss-Seidel order and math,
+  so the engines agree to fp32 round-off; this is what makes 10^3-server
+  ticks at scheduler rates feasible.
 
 ``min_vds()`` exposes the per-server normalized-VDS reduction (Eq. 16) via
 the ``kernels/psdsf_vds`` Pallas op — the scheduler-telemetry hot loop that
@@ -45,8 +47,9 @@ def _tick_jax_fn():
     jit cache instead of recompiling per instance.
 
     The tick is the sweep core's Gauss-Seidel round
-    (``psdsf_jax._gauss_round``) at alpha 1 over ``servers``, with gamma
-    zeroed for inactive users. ``buckets=(idx, mask)`` (a
+    (``psdsf_jax._gauss_round``) at alpha 1 over ``servers``, one server a
+    step in the listed order (a (len, 1) sweep: the numpy oracle's order),
+    with gamma zeroed for inactive users. ``buckets=(idx, mask)`` (a
     ``layout.BucketedLayout``'s arrays) fills each server over its
     eligibility bucket — O(nnz) per full tick; ``None`` is the full layout.
     Either way the dense state round-trips through the bucket
@@ -65,7 +68,7 @@ def _tick_jax_fn():
         gamma = jnp.where(active[:, None], gamma, 0.0)
         fill_server = _bucket_fill(mode, fill, demands, capacities, weights,
                                    gamma, idx, mask)
-        xb, _ = _gauss_round(fill_server, idx, mask, servers, n,
+        xb, _ = _gauss_round(fill_server, idx, mask, servers[:, None], n,
                              _gather(x, idx, mask), 1.0)
         return _scatter(xb, idx, mask, n)
 

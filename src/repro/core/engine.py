@@ -248,7 +248,8 @@ def _solve_psdsf_via_jax(problem: AllocationProblem, mechanism: str, x0=None,
     bucket_max = 0
     if resolved == "bucketed":
         blayout = BucketedLayout.from_support(g > 0)
-        buckets = (jnp.asarray(blayout.indices), jnp.asarray(blayout.mask))
+        buckets = tuple(jnp.asarray(a) for a in (
+            blayout.indices, blayout.mask, blayout.colours))
         bucket_max = blayout.bucket_max
     out = psdsf_solve_jax(
         jnp.asarray(problem.demands), jnp.asarray(problem.capacities),

@@ -435,34 +435,56 @@ def _bucket_fill(mode, fill, demands, capacities, weights, gamma, idx, mask):
 
 
 def _gauss_round(fill_server, idx, mask, sweep, n, xb, alpha):
-    """One damped Gauss-Seidel round over the servers ``sweep``, in order.
-    Row sums are re-derived from the buckets at the round start, then kept
-    up to date by O(Bmax) scatter-adds of each fill's delta (a bucket row
-    holds distinct user ids, so the adds never collide within a server).
-    Returns the new buckets and the round's max |delta|."""
+    """One damped Gauss-Seidel round over the server classes ``sweep``, a
+    (C, W) array of server ids padded with the sentinel K, class by class.
+    The servers of a class share no user: a fill reads and writes only its
+    own users' row sums, so one vmapped fill of the whole class gives what
+    filling them one after another does. A round is thus a Gauss-Seidel
+    round in class-major order, C serial steps; a (K, 1) sweep is the
+    one-server-a-step round. Row sums are re-derived from the buckets at
+    the round start, then kept up to date by scatter-adds of each class's
+    deltas (distinct users, so the adds never collide). Sentinel slots are
+    inert: their reads are masked, their row write dropped and their delta
+    an exact 0. Returns the new buckets and the round's max |delta|."""
+    k = idx.shape[0]
+    fill_class = jax.vmap(fill_server)
     xsum = _row_sums(xb, idx, mask, n)
 
-    def per_server(j, carry):
+    def per_class(c, carry):
         xb, xsum, resid = carry
-        i = sweep[j]
+        srv = sweep[c]                                      # (W,)
+        i = jnp.minimum(srv, k - 1)
+        m = mask[i] & (srv < k)[:, None]                    # (W, Bmax)
         u = idx[i]
         x_ext = xsum[u] - xb[i]
-        xi = jnp.where(mask[i], fill_server(i, x_ext), 0.0)
+        xi = jnp.where(m, fill_class(i, x_ext), 0.0)
         xi = (1.0 - alpha) * xb[i] + alpha * xi
-        delta = jnp.where(mask[i], xi - xb[i], 0.0)
-        return (xb.at[i].set(jnp.where(mask[i], xi, 0.0)),
+        delta = jnp.where(m, xi - xb[i], 0.0)
+        return (xb.at[srv].set(jnp.where(m, xi, 0.0), mode="drop"),
                 xsum.at[u].add(delta),
                 jnp.maximum(resid, jnp.abs(delta).max()))
 
     xb, _, resid = jax.lax.fori_loop(
-        0, sweep.shape[0], per_server,
+        0, sweep.shape[0], per_class,
         (xb, xsum, jnp.asarray(0.0, xb.dtype)))
     return xb, resid
 
 
+def _layout_arrays(buckets, n, k):
+    """``(idx, mask, colours)`` of a jitted entry point's ``buckets``:
+    ``None`` is the full layout, where every server conflicts with every
+    other (``colours`` None: one server a step); an ``(idx, mask)`` pair
+    sweeps one server a step too; ``(idx, mask, colours)`` (a
+    ``layout.BucketedLayout``'s three arrays) fills each class at once."""
+    if buckets is None:
+        return _full_layout(n, k) + (None,)
+    return tuple(buckets) + (None,) * (3 - len(buckets))
+
+
 def _solve_core(demands, capacities, weights, gamma, x0, idx, mask, mode,
                 max_rounds, tol, servers=None, alpha0=1.0, scale=None,
-                fill="event", round_mode="gauss", accel="none"):
+                fill="event", round_mode="gauss", accel="none",
+                colours=None):
     """Traced solver body shared by every jitted entry point.
 
     All array arguments are positional so ``jax.vmap`` maps over them
@@ -490,8 +512,9 @@ def _solve_core(demands, capacities, weights, gamma, x0, idx, mask, mode,
 
     ``fill`` selects the per-server fill engine (``_server_fill``).
 
-    ``round_mode`` selects the outer iteration: ``"gauss"`` (the sequential
-    Gauss-Seidel ``fori`` over servers, ``_gauss_round``) or ``"jacobi"`` —
+    ``round_mode`` selects the outer iteration: ``"gauss"`` (the
+    Gauss-Seidel ``fori`` over server classes, ``_gauss_round``) or
+    ``"jacobi"`` —
     every server fills against the PREVIOUS round's usage simultaneously,
     so one round is a single vmapped fill over the server axis (the
     vectorization the sequential ``fori`` blocks). Jacobi trades per-round
@@ -519,6 +542,9 @@ def _solve_core(demands, capacities, weights, gamma, x0, idx, mask, mode,
     scale = jnp.maximum(1.0, gamma.max() if scale is None else scale)
     n, k = gamma.shape
     dt = x0.dtype
+    if servers is not None and colours is not None:
+        raise ValueError("a servers= restriction sweeps one server a step; "
+                         "pass no colours with it")
     sweep = jnp.arange(k, dtype=jnp.int32) if servers is None else servers
     if round_mode not in ("gauss", "jacobi"):
         raise ValueError(
@@ -541,8 +567,11 @@ def _solve_core(demands, capacities, weights, gamma, x0, idx, mask, mode,
             resid = jnp.abs(new - xb[sweep]).max()
             return xb.at[sweep].set(new), resid
     else:
+        classes = sweep[:, None] if colours is None else colours
+
         def one_round(xb, alpha):
-            return _gauss_round(fill_server, idx, mask, sweep, n, xb, alpha)
+            return _gauss_round(fill_server, idx, mask, classes, n, xb,
+                                alpha)
 
     if accel == "anderson":
         xb, rounds, resid, hits, rejects = _anderson_rounds(
@@ -740,11 +769,13 @@ def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
     per-server lexicographic optimum — see ``flowrouter``); ``"bestfit"``
     is numpy-only and rejected here.
 
-    ``buckets=(idx, mask)`` (a host-built ``layout.BucketedLayout``'s
-    padded arrays) sweeps each server's eligibility bucket only — O(nnz)
-    a round, same fixed point; ``None`` is the full layout (every server
-    holds every user). The headroom repack sweeps the full layout either
-    way.
+    ``buckets=(idx, mask, colours)`` (a host-built
+    ``layout.BucketedLayout``'s padded arrays and server colouring) sweeps
+    each server's eligibility bucket only — O(nnz) a round, same fixed
+    point — and fills each colour class at once; an ``(idx, mask)`` pair
+    fills one server a step. ``None`` is the full layout (every server
+    holds every user; one server a step). The headroom repack sweeps the
+    full layout either way.
 
     ``accel="anderson"`` runs the safeguarded Anderson-mixed outer
     iteration (``_anderson_rounds``) and extends the return tuple with
@@ -757,10 +788,10 @@ def psdsf_solve_jax(demands, capacities, weights, gamma, *, x0=None,
     dtype = _solve_dtype(demands)
     if x0 is None:
         x0 = jnp.zeros((n, k), dtype=dtype)
-    idx, mask = _full_layout(n, k) if buckets is None else buckets
+    idx, mask, colours = _layout_arrays(buckets, n, k)
     out = _solve_core(demands, capacities, weights, gamma, x0.astype(dtype),
                       idx, mask, mode, max_rounds, tol, fill=fill,
-                      round_mode=round, accel=accel)
+                      round_mode=round, accel=accel, colours=colours)
     if placement == "headroom":
         fixed = _repack_refill_core(demands, capacities, weights, gamma,
                                     *out[:3], mode, max_rounds, tol,

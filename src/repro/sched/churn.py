@@ -228,6 +228,7 @@ class ChurnSimulator:
                 "headroom fill for global-share mechanisms is one-shot "
                 "global — use layout='dense'")
         self._blayout = None
+        self._buckets_j = None
         self.layout_rebuilds = 0
         self._needs_rebuild = False
         with Tracer("churn.init"):
@@ -257,9 +258,10 @@ class ChurnSimulator:
         supp = (self.problem.eligibility > 0) & self.active[:, None]
         self._blayout = BucketedLayout.from_support(supp)
         self._covered = self.active.copy()     # users the layout has slots for
-        self._idx_j = jnp.asarray(self._blayout.indices)
-        self._mask_j = jnp.asarray(self._blayout.mask)
-        count("h2d_bytes", self._idx_j.nbytes + self._mask_j.nbytes)
+        self._buckets_j = tuple(jnp.asarray(a) for a in (
+            self._blayout.indices, self._blayout.mask,
+            self._blayout.colours))
+        count("h2d_bytes", sum(a.nbytes for a in self._buckets_j))
         self._needs_rebuild = False
 
     # -- event application --------------------------------------------------
@@ -308,8 +310,7 @@ class ChurnSimulator:
                 mechanism=self.mechanism, max_rounds=self.max_rounds,
                 tol=self.tol, placement=self.placement, fill=self.fill,
                 round=self.round,
-                buckets=(None if self._blayout is None
-                         else (self._idx_j, self._mask_j)),
+                buckets=self._buckets_j,
                 accel=self.accel))
         # the scalars alone come back; the allocation stays on the device
         with span("churn.solve.download"):
@@ -407,6 +408,14 @@ class ChurnSimulator:
         # that bypassed the resolve leaves none, and the scale's floor of 1
         # is the strictest
         if swept:
+            # servers filled per serial step of a round: K over the
+            # number of colour classes the sweep ran (1 on the dense
+            # layout, K under a Jacobi round)
+            k = self.problem.num_servers
+            steps = (1 if self.round == "jacobi" else
+                     k if self._buckets_j is None
+                     else self._buckets_j[2].shape[0])
+            count("sweep_width", k / steps)
             with span("churn.certify"):
                 scale = 1.0 if resolved is None else resolved.scale
                 tight = resid <= self.tol * scale
@@ -522,7 +531,7 @@ def _resolve_fn():
     import jax
 
     from repro.core.baselines_jax import _routed_fill, level_rate_matrix_jnp
-    from repro.core.psdsf_jax import (_check_accel, _full_layout,
+    from repro.core.psdsf_jax import (_check_accel, _layout_arrays,
                                       _repack_refill_core, _solve_core,
                                       gamma_matrix_jnp)
 
@@ -570,11 +579,11 @@ def _resolve_fn():
         # departure-only churn masks bucket slots in place: a bucketed
         # layout was built from the active support, so departed users'
         # slots exist and simply go dark under the activity mask
-        idx, mask = _full_layout(*lg.shape) if buckets is None else buckets
+        idx, mask, colours = _layout_arrays(buckets, *lg.shape)
         out = _solve_core(demands, caps_eff, weights, lg, x0, idx,
                           mask & active[idx], mode, max_rounds, tol,
                           scale=scale, fill=fill, round_mode=round,
-                          accel=accel)
+                          accel=accel, colours=colours)
         if placement == "headroom":
             fixed = _repack_refill_core(demands, caps_eff, weights, g,
                                         *out[:3], mode, max_rounds, tol,

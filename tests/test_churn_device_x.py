@@ -67,7 +67,8 @@ class _HostRoundTrip:
         lay = BucketedLayout.from_support(
             (self.prob.eligibility > 0) & self.active[:, None])
         self.covered = self.active.copy()
-        self.buckets = (jnp.asarray(lay.indices), jnp.asarray(lay.mask))
+        self.buckets = tuple(jnp.asarray(a) for a in (
+            lay.indices, lay.mask, lay.colours))
 
     def _resolve(self, x0):
         return _resolve_fn()(

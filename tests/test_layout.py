@@ -14,6 +14,9 @@ Three layers of guarantees:
   sweep actually skips clean servers AND always finishes with a full
   verification sweep, so its fixed point matches the dense sweep's.
 """
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -24,9 +27,11 @@ from repro.core.instances import (cell_cluster_instance,
 from repro.core.layout import (AUTO_DENSITY_MAX, BucketedLayout,
                                resolve_layout)
 from repro.core.psdsf import solve_psdsf_rdm, solve_psdsf_tdm
-from repro.core.types import AllocationProblem
+from repro.core.properties import check_feasible_rdm
+from repro.core.types import Allocation, AllocationProblem
 
 PARITY_ATOL = 1e-9
+ROOT = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture()
@@ -42,6 +47,37 @@ def _host_full_layout(n, k):
     import jax.numpy as jnp
     lay = BucketedLayout.from_support(np.ones((n, k)))
     return jnp.asarray(lay.indices), jnp.asarray(lay.mask)
+
+
+def _rack_group_problem(seed, servers, tenants, rack_groups):
+    """The churn cell's deployment (its own build, rack groups and all) cut
+    to ``servers`` x ``tenants``."""
+    sys.path.insert(0, str(ROOT / "benchmarks" / "chip"))
+    from psbench import registry
+    cfg, deployment = registry.config(registry.load_benchmark(ROOT),
+                                   "gcd2011-synth")
+    d = deployment.build(dict(cfg, servers=servers, tenants=tenants,
+                           rack_groups=rack_groups),
+                      np.random.default_rng(seed))
+    return AllocationProblem(d.demands, d.capacities, d.weights,
+                             d.eligibility)
+
+
+def _class_major(colours, k):
+    """The server order a coloured round fills in: class by class,
+    sentinels dropped."""
+    return colours[colours < k]
+
+
+def _in_class_major_order(prob, support):
+    """``prob`` with its servers renumbered in the class-major order of the
+    bucketed layout of ``support``, and that order: a dense sweep of it
+    (one server a step, in id order) visits the servers as a coloured
+    round of ``prob`` does."""
+    order = _class_major(BucketedLayout.from_support(support).colours,
+                         prob.num_servers)
+    return AllocationProblem(prob.demands, prob.capacities[order],
+                             prob.weights, prob.eligibility[:, order]), order
 
 
 def _degenerate_problem():
@@ -136,6 +172,189 @@ class TestBucketedLayout:
         with pytest.raises(ValueError):
             resolve_layout("csr", support=sparse)
         assert AUTO_DENSITY_MAX < 1.0
+
+
+def _colouring_support(support):
+    """(support, expected colours shape or None) of a named case."""
+    if support.startswith("random-"):
+        seed, frac = support.split("-")[1:]
+        rng = np.random.default_rng(int(seed))
+        return rng.random((80, 24)) < float(frac), None
+    if support == "degenerate":
+        return _degenerate_problem().eligibility > 0, None
+    if support == "dense":
+        return np.ones((40, 12), dtype=bool), (12, 1)
+    seed = int(support.removeprefix("rack-groups-"))
+    prob = _rack_group_problem(seed, 256, 5120, 16)
+    return prob.eligibility > 0, (16, 16)
+
+
+class TestColouring:
+    """``BucketedLayout.colours`` colours the servers' conflict graph (two
+    servers conflict when they share a user), and a round that fills each
+    class at once is the one-server-a-step round in class-major order."""
+
+    @pytest.mark.parametrize("support", [
+        "random-0-0.05", "random-1-0.2", "random-2-0.5", "degenerate",
+        "rack-groups-4100000023", "rack-groups-2900000057",
+        "rack-groups-1", "dense"])
+    def test_colouring_is_valid(self, support):
+        supp, shape = _colouring_support(support)
+        k = supp.shape[1]
+        col = BucketedLayout.from_support(supp).colours
+        assert col.dtype == np.int32 and col.ndim == 2
+        if shape is not None:
+            assert col.shape == shape
+        # every server exactly once; the rest of the array is the sentinel
+        np.testing.assert_array_equal(np.sort(col[col < k]), np.arange(k))
+        assert (col[col >= k] == k).all()
+        for cls in col:
+            srv = cls[cls < k]
+            assert (np.diff(srv) > 0).all()            # ascending
+            assert (cls[srv.size:] == k).all()         # padded at the end
+            # no user on two servers of one class
+            assert supp[:, srv].sum(axis=1).max(initial=0) <= 1
+
+    @pytest.mark.parametrize("accel", ["none", "anderson"])
+    @pytest.mark.parametrize("dtype", ["float32", "float64"])
+    @pytest.mark.parametrize("support", ["rack-groups", "padded"])
+    def test_coloured_round_is_the_class_major_sweep(self, support, dtype,
+                                                     accel):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.gamma import gamma_matrix
+        from repro.core.psdsf_jax import _solve_core
+        if support == "padded":
+            prob, _ = sparse_cell_instance(num_users=600, num_servers=64,
+                                           density=0.05, cells=8, seed=6)
+        else:
+            prob = _rack_group_problem(7, 64, 1280, 4)
+        k = prob.num_servers
+        g = gamma_matrix(prob)
+        lay = BucketedLayout.from_support(g > 0)
+        assert (lay.colours == k).any() == (support == "padded")
+        assert lay.colours.shape[0] < k
+        with jax.enable_x64(dtype == "float64"):
+            args = [jnp.asarray(a, dtype) for a in (
+                prob.demands, prob.capacities, prob.weights, g,
+                np.zeros(g.shape))]
+            buckets = jnp.asarray(lay.indices), jnp.asarray(lay.mask)
+
+            def solve(**sweep):
+                return jax.jit(lambda d, c, w, gg, x0: _solve_core(
+                    d, c, w, gg, x0, *buckets, "rdm", 80, 1e-5,
+                    accel=accel, **sweep))(*args)
+
+            got = solve(colours=jnp.asarray(lay.colours))
+            want = solve(servers=jnp.asarray(_class_major(lay.colours, k)))
+        scale = max(1.0, float(g.max()))
+        assert int(got[1]) == int(want[1])
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                                   rtol=0, atol=1e-6 * scale)
+        assert float(got[2]) == pytest.approx(float(want[2]),
+                                              abs=1e-6 * scale)
+
+    @pytest.mark.parametrize("path", ["dense", "tick", "batched"])
+    def test_sequential_sweeps_keep_their_order(self, x64, path):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.gamma import gamma_matrix
+        from repro.core.psdsf_jax import (_solve_core, psdsf_solve_batched,
+                                          psdsf_solve_jax)
+        prob, _ = sparse_cell_instance(num_users=200, num_servers=32,
+                                       density=0.08, cells=4, seed=0)
+        n, k = prob.num_users, prob.num_servers
+        g = gamma_matrix(prob)
+        args = [jnp.asarray(a) for a in (prob.demands, prob.capacities,
+                                         prob.weights, g)]
+        one_a_step = jnp.arange(k, dtype=jnp.int32)[:, None]
+        if path == "dense":
+            # buckets=None: the full layout, one server a step
+            x, rounds, _ = psdsf_solve_jax(*args, max_rounds=30)
+            idx, mask = _host_full_layout(n, k)
+            x1, rounds1, _ = psdsf_solve_jax(
+                *args, max_rounds=30, buckets=(idx, mask, one_a_step))
+        elif path == "tick":
+            # the servers= tick: that list, in its order, one a step
+            from repro.core.dynamic import DistributedPSDSF
+            dist = DistributedPSDSF(prob, engine="jax", layout="bucketed")
+            dist.tick()
+            x0 = jnp.asarray(dist.x)
+            order = np.array([9, 1, 30, 5, 17, 2], dtype=np.int32)
+            dist.tick(servers=order)
+            x, rounds = dist.x, 1
+            lay = BucketedLayout.from_support(g > 0)
+            x1, rounds1, _ = jax.jit(lambda *a: _solve_core(
+                *a, x0, jnp.asarray(lay.indices), jnp.asarray(lay.mask),
+                "rdm", 1, 0.0,
+                colours=jnp.asarray(order)[:, None]))(*args)
+        else:
+            # the batched entries take (idx, mask) stacks: one a step
+            lay = BucketedLayout.from_support(g > 0)
+            idx, mask = jnp.asarray(lay.indices), jnp.asarray(lay.mask)
+            x, rounds, _ = psdsf_solve_batched(
+                *(a[None] for a in args), max_rounds=30,
+                buckets=(idx[None], mask[None]))
+            x, rounds = x[0], rounds[0]
+            x1, rounds1, _ = psdsf_solve_jax(
+                *args, max_rounds=30, buckets=(idx, mask, one_a_step))
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(x1))
+        assert int(rounds) == int(rounds1)
+
+    def test_churn_coloured_and_sequential_certify_alike(self, monkeypatch):
+        from repro.core import layout as layout_mod
+        from repro.sched.churn import ChurnEvent, ChurnSimulator
+        prob = _rack_group_problem(11, 64, 1280, 4)
+        act = np.ones(prob.num_users, dtype=bool)
+        act[:3] = False
+        batches = [[ChurnEvent(1.0, "departure", user=10),
+                    ChurnEvent(1.0, "departure", user=20)],
+                   [ChurnEvent(2.0, "arrival", user=1)],   # a rebuild
+                   [ChurnEvent(3.0, "degrade", server=2, scale=0.5)],
+                   [ChurnEvent(4.0, "departure", user=500),
+                    ChurnEvent(4.0, "arrival", user=10)],  # rebuilds
+                   [ChurnEvent(5.0, "restore", server=2)]]
+        tol = 1e-4
+
+        def sim():
+            return ChurnSimulator(prob, initial_active=act.copy(),
+                                  layout="bucketed", tol=tol)
+
+        def one_server_a_step(m):
+            m.setattr(layout_mod, "_greedy_colours",
+                      lambda indices, *_: np.arange(
+                          indices.shape[0], dtype=np.int32)[:, None])
+
+        coloured = sim()
+        with monkeypatch.context() as m:
+            one_server_a_step(m)
+            sequential = sim()
+        classes = coloured._blayout.colours.shape[0]
+        assert classes < prob.num_servers
+        rebuilds = []
+        for batch in batches:
+            with monkeypatch.context() as m:     # rebuilds colour again
+                one_server_a_step(m)
+                s = sequential.step(batch, batch[0].time)
+            c = coloured.step(batch, batch[0].time)
+            rebuilds.append(c.layout_rebuilds)
+            assert c.rounds_to_tol > 0 and s.rounds_to_tol > 0
+            assert c.trace.counters["sweep_width"] == 64 / classes
+            assert s.trace.counters["sweep_width"] == 1.0
+            scale = coloured._resolved.scale
+            assert scale == sequential._resolved.scale
+            # the split of a user's tasks over its servers is not unique
+            # (the two orders end ~1% of scale apart on single entries),
+            # so the answers are compared on per-user totals: each sums
+            # the user's 8 server shares, each certified to tol x scale
+            gap = np.abs(coloured.x.sum(1) - sequential.x.sum(1)).max()
+            assert gap <= 8 * tol * scale
+            for done in (coloured, sequential):
+                ok, msg = check_feasible_rdm(done.allocation(), tol=1e-4)
+                assert ok, msg
+        assert rebuilds == [0, 1, 0, 1, 0]
 
 
 class TestNumpyParity:
@@ -288,11 +507,12 @@ class TestJaxParity:
         from repro.core.psdsf_jax import psdsf_solve_jax
         prob, _ = sparse_cell_instance(num_users=600, num_servers=64,
                                        density=0.05, cells=8, seed=6)
+        perm, order = _in_class_major_order(prob, gamma_matrix(prob) > 0)
         for mech in ("psdsf-rdm", "psdsf-tdm"):
-            a_d, i_d = engine.solve(prob, mech, backend="jax",
-                                    layout="dense", fill="bisect",
-                                    max_rounds=40)
             if support == "full":
+                a_d, i_d = engine.solve(prob, mech, backend="jax",
+                                        layout="dense", fill="bisect",
+                                        max_rounds=40)
                 # the dense solve passed buckets=None: the full layout
                 x, rounds, _ = psdsf_solve_jax(
                     *(jnp.asarray(a) for a in (
@@ -304,11 +524,18 @@ class TestJaxParity:
                 np.testing.assert_array_equal(np.asarray(x), a_d.x)
                 assert int(rounds) == i_d.rounds
                 continue
+            # the bucketed round fills colour classes: the same sweep as
+            # the dense one over the servers in class-major order
+            a_d, i_d = engine.solve(perm, mech, backend="jax",
+                                    layout="dense", fill="bisect",
+                                    max_rounds=40)
             a_b, i_b = engine.solve(prob, mech, backend="jax",
                                     layout="bucketed", fill="bisect",
                                     max_rounds=40)
             assert i_b.layout == "bucketed" and i_b.bucket_max > 0
-            np.testing.assert_allclose(a_b.x, a_d.x, atol=PARITY_ATOL)
+            np.testing.assert_allclose(a_b.x[:, order], a_d.x,
+                                       atol=PARITY_ATOL)
+            assert i_b.rounds == i_d.rounds
 
     def test_engine_jax_auto_resolves_bucketed(self, x64):
         prob, _ = sparse_cell_instance(num_users=600, num_servers=64,
@@ -318,15 +545,21 @@ class TestJaxParity:
         assert info.layout == "bucketed"
 
     def test_engine_jax_baseline_parity(self, x64):
+        from repro.core.baselines import level_rate_matrix
         prob, _ = sparse_cell_instance(num_users=400, num_servers=64,
                                        density=0.05, cells=8, seed=8)
         for mech in ("tsf", "cdrfh"):
-            a_d, _ = engine.solve(prob, mech, backend="jax",
-                                  layout="dense", max_rounds=40)
+            # the dense sweep over the bucketed round's class-major order
+            perm, order = _in_class_major_order(
+                prob, level_rate_matrix(prob, mech) > 0)
+            a_d, i_d = engine.solve(perm, mech, backend="jax",
+                                    layout="dense", max_rounds=40)
             a_b, i_b = engine.solve(prob, mech, backend="jax",
                                     layout="bucketed", max_rounds=40)
             assert i_b.layout == "bucketed"
-            np.testing.assert_allclose(a_b.x, a_d.x, atol=PARITY_ATOL)
+            np.testing.assert_allclose(a_b.x[:, order], a_d.x,
+                                       atol=PARITY_ATOL)
+            assert i_b.rounds == i_d.rounds
 
     @pytest.mark.parametrize("support", ["eligible", "full"])
     def test_batched_parity(self, x64, support):
@@ -443,14 +676,23 @@ class TestDistributedParity:
                ChurnEvent(2.0, "departure", user=20),
                ChurnEvent(3.0, "arrival", user=1),     # outside the layout
                ChurnEvent(4.0, "degrade", server=2, scale=0.5)]
-        sd = ChurnSimulator(prob, initial_active=act.copy(),
+        # the dense simulator sweeps the servers in the class-major order
+        # of the bucketed one's colouring (the rebuild keeps it)
+        perm, order = _in_class_major_order(
+            prob, (prob.eligibility > 0) & act[:, None])
+        at = np.argsort(order)
+        evs_d = evs[:3] + [ChurnEvent(4.0, "degrade", server=int(at[2]),
+                                      scale=0.5)]
+        sd = ChurnSimulator(perm, initial_active=act.copy(),
                             layout="dense", max_rounds=200)
         sb = ChurnSimulator(prob, initial_active=act.copy(),
                             layout="bucketed", max_rounds=200)
-        rd, rb = sd.run(evs), sb.run(evs)
+        colours = sb._blayout.colours
+        rd, rb = sd.run(evs_d), sb.run(evs)
+        np.testing.assert_array_equal(sb._blayout.colours, colours)
         assert [r.rounds for r in rb] == [r.rounds for r in rd]
         assert rb[0].layout == "bucketed" and rb[0].bucket_max > 0
         assert [r.layout_rebuilds for r in rb] == [0, 0, 1, 0]
         assert sb.layout_rebuilds == 1
         scale = max(float(np.abs(sd.x).max()), 1.0)
-        assert float(np.abs(sb.x - sd.x).max()) <= 1e-5 * scale
+        assert float(np.abs(sb.x[:, order] - sd.x).max()) <= 1e-5 * scale

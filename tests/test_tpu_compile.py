@@ -107,8 +107,8 @@ def test_churn_resolve_bucketed_fits_one_chip(one_chip):
     prob, _ = sparse_cell_instance()
     assert (prob.num_users, prob.num_servers, prob.num_resources) == (
         N_USERS, N_SERVERS, N_RES)
-    assert BucketedLayout.from_support(
-        gamma_matrix(prob) > 0).bucket_max == BUCKET_MAX
+    lay = BucketedLayout.from_support(gamma_matrix(prob) > 0)
+    assert lay.bucket_max == BUCKET_MAX
     max_rounds = inspect.signature(
         ChurnSimulator).parameters["max_rounds"].default
     n, k, r, b = N_USERS, N_SERVERS, N_RES, BUCKET_MAX
@@ -118,7 +118,8 @@ def test_churn_resolve_bucketed_fits_one_chip(one_chip):
         s((n,), jnp.bool_), s((k,)), s((n, k)), mechanism="psdsf-rdm",
         max_rounds=max_rounds, tol=s(()),
         placement="level", fill="event", round="gauss",
-        buckets=(s((k, b), jnp.int32), s((k, b), jnp.bool_)),
+        buckets=(s((k, b), jnp.int32), s((k, b), jnp.bool_),
+                 s(lay.colours.shape, jnp.int32)),
         accel="none").compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -121,7 +121,7 @@ def churn():
     active[0] = False
     sim = ChurnSimulator(prob, layout="bucketed", initial_active=active)
     init = trace.recent("churn.init")[-1]
-    init_layout_bytes = sim._idx_j.nbytes + sim._mask_j.nbytes
+    init_layout_bytes = sum(a.nbytes for a in sim._buckets_j)
     first = sim.step([], 0.0)
     again = sim.step([], 1.0)
     rebuilt = sim.step([ChurnEvent(2.0, "arrival", user=0),
@@ -185,7 +185,7 @@ def test_transfer_counters_equal_the_bytes_moved(churn):
                                   + f32 * k_pad)
     rebuilt = churn["rebuilt"].trace.counters["h2d_bytes"]
     assert rebuilt == churn["again"].trace.counters["h2d_bytes"] + (
-        sim._idx_j.nbytes + sim._mask_j.nbytes)
+        sum(a.nbytes for a in sim._buckets_j))
 
 
 def test_compiles_show_on_the_first_step_only(churn):
