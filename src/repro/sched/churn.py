@@ -410,12 +410,14 @@ class ChurnSimulator:
         if swept:
             # servers filled per serial step of a round: K over the
             # number of colour classes the sweep ran (1 on the dense
-            # layout, K under a Jacobi round)
+            # layout, K under a Jacobi round); the step's serial fills
+            # are its rounds times those classes
             k = self.problem.num_servers
             steps = (1 if self.round == "jacobi" else
                      k if self._buckets_j is None
                      else self._buckets_j[2].shape[0])
             count("sweep_width", k / steps)
+            count("serial_fills", rounds * steps)
             with span("churn.certify"):
                 scale = 1.0 if resolved is None else resolved.scale
                 tight = resid <= self.tol * scale
